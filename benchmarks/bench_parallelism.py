@@ -1,16 +1,33 @@
 """Paper Fig 10 (thread sweep) — TPU analogue: device-grid sweep.
 
-The container has ONE physical core, so wall-time speedups cannot
-materialize; what the sweep shows is the work/collective split per grid
-(the structural scaling a real pod realizes). Subprocesses are used so
-each run can force its own host-device count.
+Two parts, kept apart in the row names:
+
+* ``grid_RxC_<platform>`` rows run in this process on ``jax.devices()``:
+  every grid the devices present can hold (1x1 on one chip; up to 2x2 on a
+  four-chip host). These are the only rows that time real devices.
+* ``cpu_model_grid_RxC`` rows come from child processes pinned to
+  ``JAX_PLATFORMS=cpu`` with forced host devices. They only model a larger
+  fake device grid (the work/collective split per grid) and never touch an
+  accelerator — a chip belongs to one process, which here is the parent.
+  Their times are CPU times.
 """
 import json
 import os
 import subprocess
 import sys
+import time
+
+import jax
+import numpy as np
+from jax.sharding import Mesh
 
 from benchmarks._util import row
+from repro.core.distributed import distributed_pagerank
+from repro.graph.generators import rmat
+from repro.graph.preprocess import degree_and_densify
+
+GRIDS = [(1, 1), (2, 1), (2, 2), (4, 2)]
+ITERS = 3
 
 _CHILD = r"""
 import os, sys, json, time
@@ -28,23 +45,45 @@ print(json.dumps({"sec_per_iter": dt, "m": int(el.m)}))
 """
 
 
-def run():
+def _device_rows():
+    devices = jax.devices()
+    el = degree_and_densify(*rmat(13, edge_factor=8, seed=1), drop_self_loops=True)
     rows = []
+    for r, c in GRIDS:
+        if r * c > len(devices):
+            continue
+        mesh = Mesh(np.array(devices[: r * c]).reshape(r, c), ("data", "model"))
+        t0 = time.time()
+        distributed_pagerank(el, mesh, iters=ITERS)
+        dt = (time.time() - t0) / ITERS
+        name = f"grid_{r}x{c}_{devices[0].platform}"
+        rows.append(row(name, dt, f"MTEPS={el.m / dt / 1e6:.1f}"))
+    return rows
+
+
+def _cpu_model_rows():
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(here, "src"))
-    for n_dev, r, c in [(1, 1, 1), (2, 2, 1), (4, 2, 2), (8, 4, 2)]:
+    env = dict(
+        os.environ, PYTHONPATH=os.path.join(here, "src"), JAX_PLATFORMS="cpu"
+    )
+    rows = []
+    for r, c in GRIDS:
         out = subprocess.run(
-            [sys.executable, "-c", _CHILD, str(n_dev), str(r), str(c)],
+            [sys.executable, "-c", _CHILD, str(r * c), str(r), str(c)],
             capture_output=True,
             text=True,
             env=env,
             timeout=600,
+            check=True,
         )
-        line = out.stdout.strip().splitlines()[-1]
-        d = json.loads(line)
+        d = json.loads(out.stdout.strip().splitlines()[-1])
         mteps = d["m"] / d["sec_per_iter"] / 1e6
-        rows.append((f"grid_{r}x{c}", d["sec_per_iter"], f"MTEPS={mteps:.1f}"))
-    return [row(*r) for r in rows]
+        rows.append(row(f"cpu_model_grid_{r}x{c}", d["sec_per_iter"], f"MTEPS={mteps:.1f}"))
+    return rows
+
+
+def run():
+    return _device_rows() + _cpu_model_rows()
 
 
 def main():

@@ -26,6 +26,9 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro import compile_cache
+
+    compile_cache.enable()
     skip_slow = {"fig10_parallelism"} if args.quick else set()
     print("suite,name,us_per_call,derived")
     failures = []

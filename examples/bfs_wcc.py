@@ -5,12 +5,14 @@ plus a batched 16-source BFS sharing one edge-stream pass.
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import bfs, multi_bfs, scc, wcc
 from repro.graph.generators import paper_dataset
 from repro.graph.preprocess import degree_and_densify
 
 
 def main():
+    compile_cache.enable()
     src, dst = paper_dataset("live-journal")
     el = degree_and_densify(src, dst, drop_self_loops=True)
     print(f"graph: n={el.n} m={el.m}")

@@ -15,6 +15,7 @@ import argparse
 import os
 import time
 
+from repro import compile_cache
 from repro.core import ExecutionPlan, GraphSession, PageRank, build_dsss
 from repro.graph.generators import paper_dataset
 from repro.graph.preprocess import degree_and_densify
@@ -39,6 +40,7 @@ def ensure_store(path: str, P: int) -> None:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--P", type=int, default=12)

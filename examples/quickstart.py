@@ -4,12 +4,14 @@
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ExecutionPlan, GraphSession, BFS, PageRank, build_dsss
 from repro.graph.generators import rmat
 from repro.graph.preprocess import degree_and_densify
 
 
 def main():
+    compile_cache.enable()
     # 1. raw edges -> degreeing (dense ids) -> DSSS sharding
     src, dst = rmat(12, edge_factor=8, seed=0)
     el = degree_and_densify(src, dst, drop_self_loops=True)

@@ -7,12 +7,14 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.models import Model
 from repro.serving.llm_demo import Request, ServeEngine
 
 
 def main():
+    compile_cache.enable()
     cfg = get_config("gemma-2b", smoke=True)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))

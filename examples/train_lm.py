@@ -5,11 +5,13 @@
 """
 import argparse
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.train.loop import TrainLoopConfig, train
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--arch", default="gemma-2b")

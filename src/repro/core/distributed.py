@@ -29,11 +29,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.graph.preprocess import EdgeList
 
-try:  # jax >= 0.5 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 __all__ = [
     "DeviceBlocks",
     "build_device_blocks",
@@ -150,12 +145,10 @@ def make_pagerank_step(
         return my, diff / mesh.shape[dst_axis]
 
     in_specs = (src_spec, src_spec, blk_spec, blk_spec, blk_spec)
-    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=(src_spec, P()))
-    try:
-        # check_vma only exists on newer jax; older releases call it check_rep.
-        step = shard_map(body, check_vma=False, **kwargs)
-    except TypeError:
-        step = shard_map(body, check_rep=False, **kwargs)
+    step = jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=(src_spec, P()),
+        check_vma=False,
+    )
     return jax.jit(step), (src_spec, blk_spec)
 
 

@@ -46,7 +46,7 @@ class NXGraphEngine:
         host-scheduled dispatch-per-sub-shard vs. one compiled scan per
         update sweep (chunk-streamed under host residency) vs. the fused
         Pallas tile kernel. See :class:`GraphSession`. ``None`` defaults
-        to "auto" (the best packed mode wherever one applies); results
+        to "auto" ("packed" wherever it applies); results
         and model meters are identical.
       packing: "adaptive" | "subshard" | "auto" tile layout for packed
         execution (see :class:`GraphSession`). ``None`` defaults to

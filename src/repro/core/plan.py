@@ -93,11 +93,11 @@ class ExecutionPlan:
         host residency the tile chunks are streamed with double-buffered
         prefetch, so out-of-core runs stay packed; "packed_kernel" runs
         the same sweep inside the fused Pallas kernel
-        (:mod:`repro.kernels.packed_sweep` — compiled on TPU,
-        interpret-mode elsewhere). All packed modes are SPU/DPU/MPU
-        only; fused/custom schedules downgrade to "per_block". "auto"
-        picks "packed_kernel" where Pallas compiles natively, else
-        "packed", whenever either applies. Results and modelled meters
+        (:mod:`repro.kernels.packed_sweep` — CPU interpret mode only; it
+        does not lower for TPU yet, and asking for it there raises). All
+        packed modes are SPU/DPU/MPU only; fused/custom schedules
+        downgrade to "per_block". "auto" picks "packed" wherever it
+        applies. Results and modelled meters
         are identical in every case. See
         :class:`repro.core.session.GraphSession`.
       activity: frontier-aware selective execution — ``"auto"`` (default)

@@ -703,9 +703,12 @@ def _packed_sweep_impl(
     """
     T = tiles["src"].shape[-1]
     n_pad = attrs_flat.shape[-1]
-    vert_active = jnp.repeat(
-        row_active, n_pad // row_active.shape[0], total_repeat_length=n_pad
-    )
+    # Interval mask -> per-vertex mask. A broadcast, not jnp.repeat: the
+    # TPU compiler spends ~100 s constant-folding repeat's index arithmetic
+    # at n_pad ≈ 5 M, and under a second on this.
+    P = row_active.shape[0]
+    vert_active = jnp.broadcast_to(row_active[:, None], (P, n_pad // P))
+    vert_active = vert_active.reshape(n_pad)
 
     def body(carry, tile):
         src = tile["src"]
@@ -1898,13 +1901,12 @@ class GraphSession:
           meter work exactly as under ``"packed"`` — only the sweep
           executable differs; results are bit-identical and model
           meters field-identical by construction (and by the parity
-          suite). Off-TPU backends run the kernel in interpret mode
-          (slow — validation only). Downgrades like ``"packed"`` for
+          suite). The kernel does not lower for TPU yet, so it runs
+          only on CPU, in interpret mode (validation only); requesting
+          it on a TPU backend raises. Downgrades like ``"packed"`` for
           custom/fused schedules.
-        * ``"auto"`` (default) — ``"packed_kernel"`` wherever packed
-          applies *and* the jax backend compiles Pallas natively (TPU);
-          ``"packed"`` elsewhere (an interpret-mode kernel would be a
-          de-optimization), ``"per_block"`` where neither applies.
+        * ``"auto"`` (default) — ``"packed"`` wherever packed applies,
+          ``"per_block"`` where it does not.
 
       packing: tile layout for the packed path — ``"adaptive"``
         (destination-aligned fixed-size tiles, chosen per graph to bound
@@ -2166,24 +2168,31 @@ class GraphSession:
         paths apply to the native block schedules (SPU/DPU/MPU) under
         *both* residencies — under "host" the tile chunks are streamed
         with double-buffered prefetch instead of the per-block fetcher, so
-        out-of-core runs no longer downgrade. ``"auto"`` upgrades to the
-        fused Pallas kernel only where it compiles natively (TPU backend,
-        i.e. ``not default_interpret()``); elsewhere the interpret-mode
-        kernel would be orders slower than the XLA scan, so auto keeps
-        ``"packed"`` and ``"packed_kernel"`` must be requested explicitly
-        (the parity suite does exactly that). The fused fast path and
-        custom registered schedules run per-block even when a packed mode
-        was requested explicitly (a forgiving downgrade, like
-        residency="auto": results and meters are identical).
+        out-of-core runs no longer downgrade. ``"auto"`` resolves to the
+        XLA scan ``"packed"`` on every platform: the fused Pallas kernel
+        does not lower for TPU
+        (:data:`repro.kernels.packed_sweep.TPU_LOWERING_BLOCKER`), so an
+        explicit ``"packed_kernel"`` on a TPU backend raises, and on CPU
+        it runs in interpret mode (the parity suite requests it
+        explicitly). The fused fast path and custom registered schedules
+        run per-block even when a packed mode was requested explicitly (a
+        forgiving downgrade, like residency="auto": results and meters are
+        identical).
         """
         mode = override or self.execution
         applies = strategy in ("spu", "dpu", "mpu")
         if not applies:
             return "per_block"
         if mode == "auto":
-            from repro.kernels.dsss_spmv import default_interpret
+            return "packed"
+        if mode == "packed_kernel" and jax.default_backend() == "tpu":
+            from repro.kernels.packed_sweep import TPU_LOWERING_BLOCKER
 
-            mode = "packed" if default_interpret() else "packed_kernel"
+            raise NotImplementedError(
+                "execution='packed_kernel' cannot run on TPU: "
+                f"{TPU_LOWERING_BLOCKER}. Use execution='packed' (what "
+                "'auto' resolves to)."
+            )
         return mode
 
     # -- budget accounting ---------------------------------------------------

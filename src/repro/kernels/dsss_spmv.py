@@ -57,14 +57,25 @@ assert E_BLK % MINMAX_CHUNK == 0, "chunked min/max reduce needs E_BLK % chunk ==
 
 
 def default_interpret() -> bool:
-    """Auto-select Pallas interpret mode: compile on TPU, interpret elsewhere.
+    """Pallas interpret mode for this backend: compiled on TPU, interpreted
+    on CPU, refused elsewhere.
 
-    The kernel targets the TPU lowering; on CPU/GPU backends (this
-    container, most CI) only the interpreter can execute it. Callers pass
-    ``interpret=None`` to defer to this probe; an explicit bool always
-    wins (e.g. interpret=True on TPU to debug the kernel itself).
+    The kernels target the TPU lowering; the CPU backend runs them in the
+    interpreter for the parity suites. Any other backend (a GPU, or a
+    platform this package has never run on) raises instead of silently
+    interpreting, so a device run can never hide in the interpreter.
+    Callers pass ``interpret=None`` to defer to this; an explicit bool
+    always wins (e.g. ``interpret=True`` on TPU to debug a kernel).
     """
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels here compile for TPU or interpret on CPU; backend "
+        f"{backend!r} is neither (pass interpret= explicitly to override)"
+    )
 
 
 def _kernel(

@@ -126,7 +126,7 @@ def flash_attention(
     HBM or VMEM).
 
     ``interpret=None`` auto-selects like every other kernel in this
-    package: compiled on TPU, interpret-mode elsewhere (see
+    package: compiled on TPU, interpret-mode on CPU (see
     :func:`repro.kernels.dsss_spmv.default_interpret`). ``interpret`` is
     a static jit arg, so the resolution happens at trace time.
     """

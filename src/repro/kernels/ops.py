@@ -128,8 +128,8 @@ def subshard_update(
 ) -> jax.Array:
     """Full sub-shard ToHub on the Pallas kernel; returns (num_slots,) hub.
 
-    ``interpret=None`` auto-selects: compiled on TPU, interpreted on every
-    other backend (see :func:`repro.kernels.dsss_spmv.default_interpret`).
+    ``interpret=None`` auto-selects: compiled on TPU, interpreted on CPU
+    (see :func:`repro.kernels.dsss_spmv.default_interpret`).
     """
     if interpret is None:
         interpret = default_interpret()
@@ -246,8 +246,8 @@ def attention(
 
     ``use_kernel=False`` (default on this CPU container) runs the jnp
     reference; ``use_kernel=True`` runs the Pallas flash kernel.
-    ``interpret=None`` auto-selects (compiled on TPU, interpreted
-    elsewhere — the latter validates the kernel on this container).
+    ``interpret=None`` auto-selects (compiled on TPU, interpreted on
+    CPU, where it validates the kernel).
     """
     if use_kernel:
         if interpret is None:

@@ -46,14 +46,22 @@ Masking mirrors the scan path: edges past ``e_valid`` and edges whose
 source vertex is inactive this sweep contribute exact ⊕-identities.
 
 VMEM budget: per query the kernel keeps ``attrs + acc + activity + aux``
-resident — (3 + #aux)·n_pad·4 bytes. That is the paper's own fused-tier
-assumption (intervals sized to fit fast memory); graphs whose attribute
-state outgrows VMEM belong to the scan path, which ``execution="auto"``
-keeps selecting off-TPU.
+resident plus the output block, each double-buffered by the pipeline —
+:func:`resident_vmem_bytes`. That is the paper's own fused-tier
+assumption (intervals sized to fit fast memory); larger graphs belong to
+the scan path. :func:`kernel_fits_vmem` judges the fit against the
+device's VMEM (:data:`VMEM_BYTES`), and a compiled call asks the compiler
+for exactly that footprint plus headroom as its scoped VMEM limit.
 
-``interpret=None`` resolves via :func:`repro.kernels.dsss_spmv.
-default_interpret` — compiled on TPU, interpreted elsewhere (where the
-parity suite runs it).
+TPU lowering status: every BlockSpec meets the TPU tiling rule (each
+operand carries a unit middle axis, so its ``(1, L)`` block equals the
+array's last two dims), but Mosaic still refuses the kernel body — see
+:data:`TPU_LOWERING_BLOCKER`. ``execution="auto"`` therefore never picks
+this kernel (once it lowers, ``auto`` is to pick it on TPU exactly where
+:func:`kernel_fits_vmem` holds), and an explicit ``"packed_kernel"`` on a
+TPU backend raises (``GraphSession.resolved_execution``). ``interpret=None`` resolves via
+:func:`repro.kernels.dsss_spmv.default_interpret` — interpreted on CPU,
+where the parity suites run it.
 """
 from __future__ import annotations
 
@@ -62,17 +70,36 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.identities import reduce_identity, segment_fill_value
 from repro.kernels.dsss_spmv import MINMAX_CHUNK, default_interpret
 
 __all__ = [
+    "VMEM_BYTES",
+    "kernel_fits_vmem",
     "packed_sweep_update",
     "packed_sweep_update_select",
+    "resident_vmem_bytes",
 ]
 
-# Tile leaves in kernel operand order (weights appended when present).
-_TILE_LEAVES = ("src", "dst", "run_local", "run_dst", "e_valid")
+# VMEM per TensorCore, keyed by ``jax.Device.device_kind``. A kind missing
+# here is an error, never a default: the kernel's fit cannot be judged.
+VMEM_BYTES = {
+    "TPU v5 lite": 128 * 2**20,  # v5e
+}
+# Left to Mosaic's internal scratch on top of the blocks' footprint.
+_VMEM_HEADROOM = 4 * 2**20
+
+TPU_LOWERING_BLOCKER = (
+    "the Pallas TPU lowering refuses the fused sweep kernel: its per-edge "
+    "gathers (jnp.take of an n_pad-wide VMEM vector at T source ids) hit "
+    "'Only 2D gather is supported' (Mosaic lowers only same-shape "
+    "take_along_axis gathers), and with the gathers stubbed out its "
+    "value-level dynamic indexing (the per-edge sum fold, the min/max "
+    "chunk slices and the per-slot hub scatter) hits 'Unimplemented "
+    "primitive ... dynamic_slice'"
+)
 
 
 def _combine(reduce: str, a, b):
@@ -123,9 +150,9 @@ def _kernel(
             if d_aux is not None:
                 d_aux[name] = jnp.take(arr, dst)
         else:
-            s_aux[name] = ref[0, 0]
+            s_aux[name] = ref[0]
             if d_aux is not None:
-                d_aux[name] = ref[0, 0]
+                d_aux[name] = ref[0]
     w = w_ref[0] if has_weights else None
     contrib = program.gather(vals, w, s_aux, d_aux)
     ident = reduce_identity(program.reduce, contrib.dtype)
@@ -182,11 +209,9 @@ def _kernel(
         valid = idx < n_pad  # padded slots carry the n_pad sentinel
         i = jnp.minimum(idx, n_pad - 1)
         v = jax.lax.dynamic_index_in_dim(win, r, keepdims=False)
-        cur = pl.load(out_ref, (pl.ds(0, 1), pl.ds(i, 1)))
+        cur = out_ref[pl.ds(0, 1), pl.ds(i, 1)]
         upd = _combine(program.reduce, cur, v.astype(acc_dtype))
-        pl.store(
-            out_ref, (pl.ds(0, 1), pl.ds(i, 1)), jnp.where(valid, upd, cur)
-        )
+        out_ref[pl.ds(0, 1), pl.ds(i, 1)] = jnp.where(valid, upd, cur)
         return carry
 
     jax.lax.fori_loop(0, T, run_fold, 0)
@@ -222,6 +247,44 @@ def _normalize_aux(aux: dict, aux_batched: bool, K: int):
     return tuple(spec), operands
 
 
+def _block_bytes(width: int) -> int:
+    """VMEM bytes of one (1, width) 32-bit block: its single row pads to a
+    whole 8-sublane × 128-lane tile."""
+    return 8 * (-(-width // 128) * 128) * 4
+
+
+def resident_vmem_bytes(
+    n_pad: int, T: int, n_vertex_aux: int, n_scalar_aux: int = 0,
+    has_weights: bool = False,
+) -> int:
+    """VMEM the kernel's blocks hold at one grid step, double-buffered.
+
+    attrs, the incoming accumulator, the activity mask, the output and
+    each per-vertex aux leaf are ``(1, n_pad)`` blocks; the tile leaves
+    (src, dst, run_local, run_dst, weights) are ``(1, T)``; ``e_valid``
+    and each scalar aux leaf are ``(1, 1)``. The grid holds one query's
+    blocks at a time, so the batch width K does not enter.
+    """
+    wide = (4 + n_vertex_aux) * _block_bytes(n_pad)
+    narrow = (4 + int(has_weights)) * _block_bytes(T)
+    scalars = (1 + n_scalar_aux) * _block_bytes(1)
+    return 2 * (wide + narrow + scalars)
+
+
+def kernel_fits_vmem(
+    device_kind: str, n_pad: int, T: int, n_vertex_aux: int,
+    n_scalar_aux: int = 0, has_weights: bool = False,
+) -> bool:
+    """Whether the kernel's resident footprint fits this device's VMEM."""
+    if device_kind not in VMEM_BYTES:
+        raise ValueError(
+            f"no VMEM size is known for device kind {device_kind!r} "
+            f"(known: {sorted(VMEM_BYTES)})"
+        )
+    need = resident_vmem_bytes(n_pad, T, n_vertex_aux, n_scalar_aux, has_weights)
+    return need + _VMEM_HEADROOM <= VMEM_BYTES[device_kind]
+
+
 def packed_sweep_update(
     program,
     attrs_flat: jax.Array,  # (K, n_pad) previous attributes (read-only)
@@ -244,28 +307,35 @@ def packed_sweep_update(
         interpret = default_interpret()
     K, n_pad = attrs_flat.shape
     NT, T = tiles["src"].shape
-    vert_active = jnp.repeat(
-        row_active, n_pad // row_active.shape[0], total_repeat_length=n_pad
-    ).astype(jnp.int32)[None, :]
+    P = row_active.shape[0]
+    vert_active = jnp.broadcast_to(row_active[:, None], (P, n_pad // P))
+    vert_active = vert_active.astype(jnp.int32).reshape(1, n_pad)
     aux_spec, aux_ops = _normalize_aux(aux, aux_batched, K)
 
-    def _bcast(op):  # (Ka, L): shared leaves pin block 0 on the query axis
-        ka = op.shape[0]
+    # Every operand gets a unit middle axis: a (1, L) block over a (R, 1, L)
+    # array has last two dims equal to the array's, which is what the TPU
+    # tiling rule asks of blocks that are not (8, 128)-divisible.
+    def _rows(op):  # (R, L) -> (R, 1, L)
+        return op.reshape(op.shape[0], 1, op.shape[1])
+
+    def _spec(width, per_row):
         return pl.BlockSpec(
-            (1, op.shape[1]),
-            (lambda k, t: (k, 0)) if ka == K else (lambda k, t: (0, 0)),
+            (pl.Squeezed(), 1, width),
+            (lambda k, t: (k, 0, 0)) if per_row == "query"
+            else (lambda k, t: (t, 0, 0)) if per_row == "tile"
+            else (lambda k, t: (0, 0, 0)),
         )
 
     in_specs = [
-        pl.BlockSpec((1, n_pad), lambda k, t: (k, 0)),  # attrs
-        pl.BlockSpec((1, n_pad), lambda k, t: (k, 0)),  # acc in
-        pl.BlockSpec((1, n_pad), lambda k, t: (0, 0)),  # activity
-        *[_bcast(op) for op in aux_ops],
-        pl.BlockSpec((1, T), lambda k, t: (t, 0)),  # src
-        pl.BlockSpec((1, T), lambda k, t: (t, 0)),  # dst
-        pl.BlockSpec((1, T), lambda k, t: (t, 0)),  # run_local
-        pl.BlockSpec((1, T), lambda k, t: (t, 0)),  # run_dst
-        pl.BlockSpec((1,), lambda k, t: (t,)),  # e_valid
+        _spec(n_pad, "query"),  # attrs
+        _spec(n_pad, "query"),  # acc in
+        _spec(n_pad, "shared"),  # activity
+        *[
+            _spec(op.shape[1], "query" if op.shape[0] == K else "shared")
+            for op in aux_ops
+        ],
+        *[_spec(T, "tile") for _ in range(4)],  # src, dst, run_local, run_dst
+        _spec(1, "tile"),  # e_valid
     ]
     operands = [
         attrs_flat,
@@ -276,12 +346,21 @@ def packed_sweep_update(
         tiles["dst"],
         tiles["run_local"],
         tiles["run_dst"],
-        tiles["e_valid"],
+        tiles["e_valid"][:, None],
     ]
     if has_weights:
-        in_specs.append(pl.BlockSpec((1, T), lambda k, t: (t, 0)))
+        in_specs.append(_spec(T, "tile"))
         operands.append(tiles["weights"])
-    return pl.pallas_call(
+    compiler_params = None
+    if not interpret:
+        n_vertex = sum(kind == "vertex" for _, kind in aux_spec)
+        vmem = resident_vmem_bytes(
+            n_pad, T, n_vertex, len(aux_spec) - n_vertex, has_weights
+        )
+        compiler_params = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem + _VMEM_HEADROOM
+        )
+    out = pl.pallas_call(
         functools.partial(
             _kernel,
             program=program,
@@ -292,10 +371,12 @@ def packed_sweep_update(
         ),
         grid=(K, NT),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, n_pad), lambda k, t: (k, 0)),
-        out_shape=jax.ShapeDtypeStruct((K, n_pad), acc_flat.dtype),
+        out_specs=_spec(n_pad, "query"),
+        out_shape=jax.ShapeDtypeStruct((K, 1, n_pad), acc_flat.dtype),
+        compiler_params=compiler_params,
         interpret=interpret,
-    )(*operands)
+    )(*[_rows(op) for op in operands])
+    return out.reshape(K, n_pad)
 
 
 def packed_sweep_update_select(

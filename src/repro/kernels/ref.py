@@ -4,8 +4,7 @@ These are the ground truth the kernels are swept against in
 tests/test_kernels_*.py and tests/test_packed_kernel_property.py (shape ×
 dtype × feature sweeps). The kernels themselves resolve ``interpret``
 via :func:`repro.kernels.dsss_spmv.default_interpret` — compiled on TPU,
-interpret-mode on every other backend, which is how the sweeps execute
-them on CPU CI.
+interpret-mode on CPU, which is how the sweeps execute them in CI.
 """
 from __future__ import annotations
 

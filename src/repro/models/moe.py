@@ -187,11 +187,6 @@ def _moe_fsdp_local(params, x, cfg: ModelConfig, mesh, rules, return_aux):
 
     from repro.sharding.rules import spec_for
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
     m = cfg.moe
     b, s, d = x.shape
     e = m.num_experts
@@ -256,7 +251,7 @@ def _moe_fsdp_local(params, x, cfg: ModelConfig, mesh, rules, return_aux):
     if has_shared:
         in_specs += [swi_spec, swo_spec]
         args += [params["shared"]["wi"], params["shared"]["wo"]]
-    y, lbl, dropped = shard_map(
+    y, lbl, dropped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=tuple(in_specs),

@@ -178,21 +178,16 @@ def test_kernel_path_actually_runs(monkeypatch):
     assert len(calls) == 3  # one fused-kernel dispatch per update sweep
 
 
-def test_auto_resolution_tracks_backend():
-    """auto → the kernel only where Pallas compiles natively; explicit
-    "packed_kernel" is honored everywhere; fused/custom downgrade."""
+def test_auto_resolution_tracks_backend(monkeypatch):
+    """auto → the XLA scan on every backend; explicit "packed_kernel" is
+    honored on CPU (interpreted) and refused on TPU, where the kernel does
+    not lower; fused/custom downgrade."""
     import jax
-
-    from repro.kernels.dsss_spmv import default_interpret
 
     g = _graph(seed=1)
     sess = GraphSession(g)
-    auto = sess.resolved_execution("spu", "device")
-    if default_interpret():
-        assert jax.default_backend() != "tpu"
-        assert auto == "packed"
-    else:
-        assert auto == "packed_kernel"
+    assert jax.default_backend() == "cpu"
+    assert sess.resolved_execution("spu", "device") == "packed"
     assert sess.resolved_execution("spu", "device", "packed_kernel") == (
         "packed_kernel"
     )
@@ -203,6 +198,11 @@ def test_auto_resolution_tracks_backend():
         ExecutionPlan(PageRank(), strategy="dpu", execution="packed_kernel")
     )
     assert compiled.execution == "packed_kernel"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sess.resolved_execution("spu", "host") == "packed"
+    with pytest.raises(NotImplementedError, match="Only 2D gather"):
+        sess.resolved_execution("spu", "device", "packed_kernel")
 
 
 def test_src_sorted_subshard_tiles_parity():
