@@ -151,7 +151,6 @@ def bench_one(session, strategy, execution, iters):
         "per_sweep_seconds": res.meters.wall_seconds / res.iterations,
         "dispatches_per_sweep": counter.count / res.iterations,
         "fused_dispatches_per_sweep": counter.kernel_count / res.iterations,
-        "mteps": res.meters.mteps(),
         "h2d_per_sweep": res.meters.bytes_h2d / res.iterations,
         "attrs": res.attrs,
         "meters": res.meters,

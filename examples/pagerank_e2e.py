@@ -87,7 +87,7 @@ def main():
     m = res.meters
     print(
         f"{res.iterations} iterations in {m.wall_seconds:.2f}s "
-        f"({m.wall_seconds/res.iterations:.3f}s/iter, {m.mteps():.1f} MTEPS)"
+        f"({m.wall_seconds/res.iterations:.3f}s/iter)"
     )
     print(
         f"slow-tier: read {m.bytes_read/1e6:.1f}MB write {m.bytes_written/1e6:.1f}MB"

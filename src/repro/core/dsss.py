@@ -23,6 +23,7 @@ import dataclasses
 import numpy as np
 
 from repro.graph.preprocess import EdgeList
+from repro.obs.trace import NO_SPAN, TRACER
 
 __all__ = [
     "DSSSGraph",
@@ -529,59 +530,23 @@ class DSSSGraph:
         return (self.offsets[:, 1:] - self.offsets[:, :-1]).astype(np.int64)
 
 
-def build_dsss(
-    el: EdgeList,
+def _hub_slots(
+    dst_s: np.ndarray,
+    flat_offsets: np.ndarray,
     P: int,
-    *,
-    src_sorted: bool = False,
-) -> DSSSGraph:
-    """The sharding pass (paper §III-A).
+    isz: int,
+    src_sorted: bool,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Hub (unique destination) compression per sub-shard.
 
-    Args:
-      el: degreed (dense-id) edge list.
-      P: number of intervals. The paper uses equal-sized vertex ranges and
-        relies on fine-grained parallelism to absorb sub-shard imbalance.
-      src_sorted: build the *GraphChi-like* layout instead (edges sorted by
-        source within each sub-shard) — the ablation baseline of paper
-        Table IV. Engine behaviour is identical; only memory-access order
-        and the parallel reduction granularity change.
+    Returns ``(hub_dst_flat, hub_inv_flat, hub_counts)``: the concatenated
+    local unique destinations, each edge's slot within its sub-shard's
+    hub list, and the (P·P,) hub counts. Because edges are
+    destination-sorted inside each sub-shard, uniques are found with one
+    vectorized pass: a new hub slot opens wherever dst changes or a new
+    sub-shard begins.
     """
-    if P < 1:
-        raise ValueError("P must be >= 1")
-    n, m = el.n, el.m
-    interval_size = -(-n // P)  # ceil
-    src = el.src.astype(np.int64)
-    dst = el.dst.astype(np.int64)
-    si = src // interval_size  # source interval of each edge
-    dj = dst // interval_size  # destination interval
-    # Order edges by (source interval, dest interval) block, then by the
-    # in-block DSSS order: destination id, then source id. np.lexsort keys
-    # are *last-key-major*.
-    if src_sorted:
-        order = np.lexsort((dst, src, dj, si))
-    else:
-        order = np.lexsort((src, dst, dj, si))
-    src_s = src[order].astype(np.int32)
-    dst_s = dst[order].astype(np.int32)
-    w_s = None if el.weights is None else el.weights[order]
-
-    # offsets[i, j] via 2-D histogram of block ids.
-    block = si[order] * P + dj[order]
-    counts = np.bincount(block, minlength=P * P).reshape(P, P)
-    flat_offsets = np.zeros(P * P + 1, dtype=np.int64)
-    np.cumsum(counts.ravel(), out=flat_offsets[1:])
-    offsets = np.zeros((P, P + 1), dtype=np.int64)
-    offsets[:, 0] = flat_offsets[:-1].reshape(P, P)[:, 0]
-    offsets[:, 1:] = flat_offsets[1:].reshape(P, P)
-
-    # Hub (unique destination) compression per sub-shard. Because edges are
-    # destination-sorted inside each sub-shard, uniques are found with one
-    # vectorized pass: a new hub slot opens wherever dst changes or a new
-    # sub-shard begins.
-    isz = interval_size
-    starts = flat_offsets[:-1]
-    is_block_start = np.zeros(m, dtype=bool)
-    is_block_start[starts[starts < m]] = True
+    m = dst_s.shape[0]
     if src_sorted:
         # Destinations are not sorted inside a block; fall back to per-block
         # np.unique (the baseline pays this cost, as in the paper).
@@ -600,57 +565,117 @@ def build_dsss(
         hub_dst_flat = (
             np.concatenate(hub_dst_parts) if hub_dst_parts else np.zeros(0, np.int32)
         )
-    else:
-        new_slot = np.ones(m, dtype=bool)
-        if m > 1:
-            new_slot[1:] = (dst_s[1:] != dst_s[:-1]) | is_block_start[1:]
-        slot_global = np.cumsum(new_slot) - 1 if m else np.zeros(0, np.int64)
-        hub_dst_flat = (
-            (dst_s[new_slot] - (dst_s[new_slot] // isz) * isz).astype(np.int32)
-            if m
-            else np.zeros(0, np.int32)
+        return hub_dst_flat, hub_inv_flat, hub_counts
+    if not m:
+        return (
+            np.zeros(0, np.int32),
+            np.zeros(0, np.int32),
+            np.zeros(P * P, dtype=np.int64),
         )
-        # per-block slot base = slot_global at block start
-        hub_counts = np.zeros(P * P, dtype=np.int64)
-        if m:
-            blk_of_slot = np.repeat(
-                np.arange(P * P), np.diff(flat_offsets)
-            )[new_slot]
-            hub_counts = np.bincount(blk_of_slot, minlength=P * P)
-            slot_base = np.zeros(P * P, dtype=np.int64)
-            np.cumsum(hub_counts[:-1], out=slot_base[1:])
-            hub_inv_flat = (
-                slot_global - np.repeat(slot_base, np.diff(flat_offsets))
-            ).astype(np.int32)
-        else:
-            hub_inv_flat = np.zeros(0, np.int32)
+    starts = flat_offsets[:-1]
+    is_block_start = np.zeros(m, dtype=bool)
+    is_block_start[starts[starts < m]] = True
+    new_slot = np.ones(m, dtype=bool)
+    new_slot[1:] = (dst_s[1:] != dst_s[:-1]) | is_block_start[1:]
+    slot_global = np.cumsum(new_slot) - 1
+    hub_dst_flat = (
+        dst_s[new_slot] - (dst_s[new_slot] // isz) * isz
+    ).astype(np.int32)
+    # per-block slot base = slot_global at block start
+    blk_of_slot = np.repeat(np.arange(P * P), np.diff(flat_offsets))[new_slot]
+    hub_counts = np.bincount(blk_of_slot, minlength=P * P)
+    slot_base = np.zeros(P * P, dtype=np.int64)
+    np.cumsum(hub_counts[:-1], out=slot_base[1:])
+    hub_inv_flat = (
+        slot_global - np.repeat(slot_base, np.diff(flat_offsets))
+    ).astype(np.int32)
+    return hub_dst_flat, hub_inv_flat, hub_counts
 
-    hub_offsets = np.zeros((P, P + 1), dtype=np.int64)
-    hub_cum = np.zeros(P * P + 1, dtype=np.int64)
-    np.cumsum(hub_counts, out=hub_cum[1:])
-    hub_offsets[:, 0] = hub_cum[:-1].reshape(P, P)[:, 0]
-    hub_offsets[:, 1:] = hub_cum[1:].reshape(P, P)
 
-    n_pad = P * interval_size
-    out_deg = np.zeros(n_pad, dtype=np.int32)
-    out_deg[:n] = el.out_degree
-    in_deg = np.zeros(n_pad, dtype=np.int32)
-    in_deg[:n] = el.in_degree
+def build_dsss(
+    el: EdgeList,
+    P: int,
+    *,
+    src_sorted: bool = False,
+) -> DSSSGraph:
+    """The sharding pass (paper §III-A).
 
-    return DSSSGraph(
-        n=n,
-        m=m,
-        P=P,
-        interval_size=interval_size,
-        src=src_s,
-        dst=dst_s,
-        weights=w_s,
-        offsets=offsets,
-        out_degree=out_deg,
-        in_degree=in_deg,
-        hub_dst_flat=hub_dst_flat,
-        hub_inv_flat=hub_inv_flat,
-        hub_offsets=hub_offsets,
-        edgelist=el,
-        src_sorted=src_sorted,
-    )
+    Args:
+      el: degreed (dense-id) edge list.
+      P: number of intervals. The paper uses equal-sized vertex ranges and
+        relies on fine-grained parallelism to absorb sub-shard imbalance.
+      src_sorted: build the *GraphChi-like* layout instead (edges sorted by
+        source within each sub-shard) — the ablation baseline of paper
+        Table IV. Engine behaviour is identical; only memory-access order
+        and the parallel reduction granularity change.
+
+    Traced as ``preprocess.build_dsss`` with the sub-spans
+    ``build_dsss.sort`` (the block-order ``lexsort`` and permutation),
+    ``build_dsss.blocks`` (the sub-shard offsets) and ``build_dsss.hubs``
+    (hub compression).
+    """
+    if P < 1:
+        raise ValueError("P must be >= 1")
+    tracing = TRACER.enabled
+    with TRACER.span("preprocess.build_dsss") if tracing else NO_SPAN:
+        n, m = el.n, el.m
+        interval_size = -(-n // P)  # ceil
+        src = el.src.astype(np.int64)
+        dst = el.dst.astype(np.int64)
+        si = src // interval_size  # source interval of each edge
+        dj = dst // interval_size  # destination interval
+        # Order edges by (source interval, dest interval) block, then by the
+        # in-block DSSS order: destination id, then source id. np.lexsort
+        # keys are *last-key-major*.
+        with TRACER.span("build_dsss.sort") if tracing else NO_SPAN:
+            if src_sorted:
+                order = np.lexsort((dst, src, dj, si))
+            else:
+                order = np.lexsort((src, dst, dj, si))
+            src_s = src[order].astype(np.int32)
+            dst_s = dst[order].astype(np.int32)
+            w_s = None if el.weights is None else el.weights[order]
+
+        # offsets[i, j] via 2-D histogram of block ids.
+        with TRACER.span("build_dsss.blocks") if tracing else NO_SPAN:
+            block = si[order] * P + dj[order]
+            counts = np.bincount(block, minlength=P * P).reshape(P, P)
+            flat_offsets = np.zeros(P * P + 1, dtype=np.int64)
+            np.cumsum(counts.ravel(), out=flat_offsets[1:])
+            offsets = np.zeros((P, P + 1), dtype=np.int64)
+            offsets[:, 0] = flat_offsets[:-1].reshape(P, P)[:, 0]
+            offsets[:, 1:] = flat_offsets[1:].reshape(P, P)
+
+        with TRACER.span("build_dsss.hubs") if tracing else NO_SPAN:
+            hub_dst_flat, hub_inv_flat, hub_counts = _hub_slots(
+                dst_s, flat_offsets, P, interval_size, src_sorted
+            )
+            hub_offsets = np.zeros((P, P + 1), dtype=np.int64)
+            hub_cum = np.zeros(P * P + 1, dtype=np.int64)
+            np.cumsum(hub_counts, out=hub_cum[1:])
+            hub_offsets[:, 0] = hub_cum[:-1].reshape(P, P)[:, 0]
+            hub_offsets[:, 1:] = hub_cum[1:].reshape(P, P)
+
+        n_pad = P * interval_size
+        out_deg = np.zeros(n_pad, dtype=np.int32)
+        out_deg[:n] = el.out_degree
+        in_deg = np.zeros(n_pad, dtype=np.int32)
+        in_deg[:n] = el.in_degree
+
+        return DSSSGraph(
+            n=n,
+            m=m,
+            P=P,
+            interval_size=interval_size,
+            src=src_s,
+            dst=dst_s,
+            weights=w_s,
+            offsets=offsets,
+            out_degree=out_deg,
+            in_degree=in_deg,
+            hub_dst_flat=hub_dst_flat,
+            hub_inv_flat=hub_inv_flat,
+            hub_offsets=hub_offsets,
+            edgelist=el,
+            src_sorted=src_sorted,
+        )

@@ -116,6 +116,7 @@ from repro.core.iomodel import (
 from repro.core.plan import ExecutionPlan
 from repro.core.vertex_programs import VertexProgram, reduce_identity
 from repro.obs.registry import REGISTRY as _REGISTRY
+from repro.obs.trace import NO_SPAN
 from repro.obs.trace import TRACER as _TRACER
 from repro.reliability.checkpoint import (
     SnapshotError,
@@ -160,11 +161,12 @@ MODEL_METER_FIELDS = (
 
 
 # ---------------------------------------------------------------------------
-# Observability handles (repro.obs). The byte counter is incremented on the
-# same lines that charge the corresponding Meters field — physical kinds
-# (h2d, disk_read) at the transfer/mmap boundary, model kinds per sweep —
-# so a run's registry deltas recombine field-for-field with Result.meters
-# (tests/test_obs.py). All no-ops under REPRO_OBS=0.
+# Observability handles (repro.obs). The physical byte kinds (h2d,
+# disk_read) are incremented on the same lines that charge the Meters
+# field, at the transfer/mmap boundary, so a live scrape sees them mid-run;
+# the model kinds and the sweep count are published once per run from
+# Meters. Either way a run's registry deltas recombine field-for-field
+# with Result.meters (tests/test_obs.py). All no-ops under REPRO_OBS=0.
 # ---------------------------------------------------------------------------
 _OBS_BYTES = _REGISTRY.counter(
     "repro_engine_bytes_total",
@@ -173,7 +175,7 @@ _OBS_BYTES = _REGISTRY.counter(
 )
 _OBS_H2D = _OBS_BYTES.labels(kind="h2d")
 _OBS_DISK = _OBS_BYTES.labels(kind="disk_read")
-# Model-unit byte fields, charged as per-sweep deltas in _execute.
+# Model-unit byte fields, published as run deltas at the end of _execute.
 _OBS_MODEL_BYTES = tuple(
     (f, _OBS_BYTES.labels(kind=f[len("bytes_"):]))
     for f in MODEL_METER_FIELDS
@@ -181,6 +183,11 @@ _OBS_MODEL_BYTES = tuple(
 )
 _OBS_SWEEPS = _REGISTRY.counter(
     "repro_engine_sweeps_total", "Update sweeps executed"
+)
+_OBS_TILES = _REGISTRY.counter(
+    "repro_engine_tiles_swept_total",
+    "Packed tiles covered by dispatched sweep scans (active tiles only "
+    "under selective execution; the bucket padding is not counted)",
 )
 _OBS_RUNS = _REGISTRY.counter(
     "repro_engine_runs_total",
@@ -275,12 +282,6 @@ class Meters:
         ):
             setattr(out, f, getattr(self, f) / k)
         return out
-
-    def mteps(self) -> float:
-        """Million traversed edges per second (paper Fig. 11 metric)."""
-        if self.wall_seconds <= 0:
-            return float("nan")
-        return self.edges_processed / self.wall_seconds / 1e6
 
     def merge(self, other: "Meters") -> "Meters":
         """Accumulate another run's counters into this one (in place).
@@ -710,39 +711,51 @@ def _packed_sweep_impl(
     vert_active = jnp.broadcast_to(row_active[:, None], (P, n_pad // P))
     vert_active = vert_active.reshape(n_pad)
 
+    segment_reduce = {
+        "sum": jax.ops.segment_sum,
+        "min": jax.ops.segment_min,
+        "max": jax.ops.segment_max,
+    }[program.reduce]
+
+    # The three phases carry named scopes, so the op profile and HLO dumps
+    # attribute each fusion of the step to gather, segment reduce or fold.
     def body(carry, tile):
         src = tile["src"]
         dst = tile["dst"]
         run = tile["run_local"]
         run_dst = tile["run_dst"]
         w = tile["weights"] if has_weights else None
-        mask = (jnp.arange(T) < tile["e_valid"]) & vert_active[src]
+        with jax.named_scope("gather"):
+            mask = (jnp.arange(T) < tile["e_valid"]) & vert_active[src]
 
         def one(pv, aq, auxq):
-            vals = pv[src]
-            s_aux = {
-                k: (v[src] if getattr(v, "ndim", 0) == 1 else v)
-                for k, v in auxq.items()
-            }
-            d_aux = (
-                {
-                    k: (v[dst] if getattr(v, "ndim", 0) == 1 else v)
+            with jax.named_scope("gather"):
+                vals = pv[src]
+                s_aux = {
+                    k: (v[src] if getattr(v, "ndim", 0) == 1 else v)
                     for k, v in auxq.items()
                 }
-                if program.needs_dst_aux
-                else None
-            )
-            contrib = program.gather(vals, w, s_aux, d_aux)
-            ident = reduce_identity(program.reduce, contrib.dtype)
-            contrib = jnp.where(mask, contrib, ident)
-            if program.reduce == "sum":
-                red = jax.ops.segment_sum(contrib, run, num_segments=T)
-                return aq.at[run_dst].add(red.astype(aq.dtype), mode="drop")
-            if program.reduce == "min":
-                red = jax.ops.segment_min(contrib, run, num_segments=T)
-                return aq.at[run_dst].min(red.astype(aq.dtype), mode="drop")
-            red = jax.ops.segment_max(contrib, run, num_segments=T)
-            return aq.at[run_dst].max(red.astype(aq.dtype), mode="drop")
+                d_aux = (
+                    {
+                        k: (v[dst] if getattr(v, "ndim", 0) == 1 else v)
+                        for k, v in auxq.items()
+                    }
+                    if program.needs_dst_aux
+                    else None
+                )
+                contrib = program.gather(vals, w, s_aux, d_aux)
+                ident = reduce_identity(program.reduce, contrib.dtype)
+                contrib = jnp.where(mask, contrib, ident)
+            with jax.named_scope("segment_reduce"):
+                red = segment_reduce(contrib, run, num_segments=T)
+            with jax.named_scope("scatter_fold"):
+                red = red.astype(aq.dtype)
+                fold = aq.at[run_dst]
+                if program.reduce == "sum":
+                    return fold.add(red, mode="drop")
+                if program.reduce == "min":
+                    return fold.min(red, mode="drop")
+                return fold.max(red, mode="drop")
 
         return (
             jax.vmap(one, in_axes=(0, 0, _aux_axes(aux, aux_batched)))(
@@ -785,9 +798,10 @@ def _apply_all_impl(
         q_axes = {k: None for k in aux2}
 
     def per_interval(o, a, auxv, v, gl):
-        new = program.apply(o, a, auxv, gl)
-        new = jnp.where(v, new, o)
-        changed = jnp.any(program.changed(o, new, tol) & v)
+        with jax.named_scope("apply"):
+            new = program.apply(o, a, auxv, gl)
+            new = jnp.where(v, new, o)
+            changed = jnp.any(program.changed(o, new, tol) & v)
         return new, changed
 
     def per_query(o, a, auxq, gl):
@@ -942,6 +956,8 @@ class _RunContext:
     activity: str = "off"  # resolved activity ("selective" | "off")
     aux_batched: bool = False  # aux leaves carry a leading (K,) query axis
     execution: str = "per_block"  # resolved execution (never "auto")
+    trace: bool = False  # record the sweep's phase spans (TraceSpec.sweeps)
+    tiles_swept: int = 0  # tiles the run's scans covered so far
 
     @property
     def block_keys(self) -> frozenset:
@@ -1319,10 +1335,13 @@ def _sweep_tile_slab(
     sess, prog = ctx.session, ctx.program
     hw = sess.has_weights
     if window is None or window.all():
-        return sweep(
-            prog, attrs_flat, acc, ctx.aux, tiles, row_active,
-            has_weights=hw, aux_batched=ctx.aux_batched,
-        )
+        n = int(tiles["e_valid"].shape[0])
+        _count_tiles(ctx, n)
+        with _TRACER.span("sweep.scan", tiles=n) if ctx.trace else NO_SPAN:
+            return sweep(
+                prog, attrs_flat, acc, ctx.aux, tiles, row_active,
+                has_weights=hw, aux_batched=ctx.aux_batched,
+            )
     local = np.flatnonzero(window)
     if local.size == 0:
         return acc
@@ -1336,11 +1355,24 @@ def _sweep_tile_slab(
         else _packed_select_jits
     )
     select = select_jits(jax.default_backend() != "cpu")
-    return select(
-        prog, attrs_flat, acc, ctx.aux, tiles,
-        jnp.asarray(idx), jnp.asarray(np.int32(local.size)), row_active,
-        has_weights=hw, aux_batched=ctx.aux_batched,
-    )
+    _count_tiles(ctx, int(local.size))
+    with (
+        _TRACER.span("sweep.scan", tiles=int(local.size), bucket=bucket)
+        if ctx.trace
+        else NO_SPAN
+    ):
+        return select(
+            prog, attrs_flat, acc, ctx.aux, tiles,
+            jnp.asarray(idx), jnp.asarray(np.int32(local.size)), row_active,
+            has_weights=hw, aux_batched=ctx.aux_batched,
+        )
+
+
+def _count_tiles(ctx: _RunContext, n: int) -> None:
+    """Charge ``n`` tiles to the run and ``repro_engine_tiles_swept_total``
+    at the scan's dispatch site."""
+    ctx.tiles_swept += n
+    _OBS_TILES.inc(n)
 
 
 def _packed_host_sweep(
@@ -1423,22 +1455,26 @@ def _packed_host_sweep(
 
     cur = fetch(0)
     for idx in range(len(starts)):
-        nxt = fetch(idx + 1) if idx + 1 < len(starts) else None
-        host, dev, model, cached = cur
-        nb = _chunk_nbytes(host)
-        meters.bytes_h2d += nb
-        _OBS_H2D.inc(nb)
-        if disk and not cached:
-            meters.bytes_disk_read += nb
-            _OBS_DISK.inc(nb)
-        live = pin_model + model + (nxt[2] if nxt is not None else 0.0)
-        meters.peak_device_graph_bytes = max(
-            meters.peak_device_graph_bytes, live
-        )
-        acc = sweep(
-            prog, attrs_flat, acc, ctx.aux, dev, row_active,
-            has_weights=hw, aux_batched=ctx.aux_batched,
-        )
+        n = min(starts[idx] + splan.chunk_tiles, nt) - starts[idx]
+        _count_tiles(ctx, n)
+        # One span per chunk: the next chunk's H2D, then this one's dispatch.
+        with _TRACER.span("sweep.chunk", tiles=n) if ctx.trace else NO_SPAN:
+            nxt = fetch(idx + 1) if idx + 1 < len(starts) else None
+            host, dev, model, cached = cur
+            nb = _chunk_nbytes(host)
+            meters.bytes_h2d += nb
+            _OBS_H2D.inc(nb)
+            if disk and not cached:
+                meters.bytes_disk_read += nb
+                _OBS_DISK.inc(nb)
+            live = pin_model + model + (nxt[2] if nxt is not None else 0.0)
+            meters.peak_device_graph_bytes = max(
+                meters.peak_device_graph_bytes, live
+            )
+            acc = sweep(
+                prog, attrs_flat, acc, ctx.aux, dev, row_active,
+                has_weights=hw, aux_batched=ctx.aux_batched,
+            )
         cur = nxt
     return acc
 
@@ -1450,34 +1486,42 @@ def _iteration_packed(ctx: _RunContext, attrs, active, meters: Meters):
     packed tiles (or one per streamed tile chunk under host residency) →
     one batched apply. The per-strategy slow-tier meters are charged from
     the packed metadata before the compiled pass runs.
+
+    Traced phases (``ctx.trace``): ``sweep.plan`` (rows, meter charges,
+    pre-iteration globals, tile activity), ``sweep.scan`` /
+    ``sweep.chunk`` (the scan dispatches), ``sweep.apply`` (the apply
+    dispatch) and ``sweep.sync`` (the host read of ``changed``).
     """
     sess, prog = ctx.session, ctx.program
     g = sess.graph
     K = ctx.K
     strategy = ctx.choice.strategy
-    rows = _rows_to_process(ctx, active)
-    if strategy == "spu":
-        _charge_packed_spu(ctx, rows, meters)
-    else:
-        _charge_packed_two_phase(
-            ctx, rows, meters, Q=0 if strategy == "dpu" else ctx.choice.Q
+    with _TRACER.span("sweep.plan") if ctx.trace else NO_SPAN:
+        rows = _rows_to_process(ctx, active)
+        if strategy == "spu":
+            _charge_packed_spu(ctx, rows, meters)
+        else:
+            _charge_packed_two_phase(
+                ctx, rows, meters, Q=0 if strategy == "dpu" else ctx.choice.Q
+            )
+        globals_ = _pre_iteration(
+            prog, attrs.reshape(K, -1), ctx.aux, aux_batched=ctx.aux_batched
         )
-    globals_ = _pre_iteration(
-        prog, attrs.reshape(K, -1), ctx.aux, aux_batched=ctx.aux_batched
-    )
-    ident = reduce_identity(prog.reduce, prog.dtype)
-    attrs_flat = attrs.reshape(K, g.n_pad)
-    acc = jnp.full((K, g.n_pad), ident, prog.dtype)
-    row_mask = np.zeros(g.P, dtype=bool)
-    row_mask[rows] = True
-    row_active = jnp.asarray(row_mask)
-    # Selective execution: map the interval frontier onto the tile axis
-    # (a tile is active iff any source interval in its span is) and run
-    # the sweep compacted to active tiles / active streamed chunks. A
-    # full frontier short-circuits to the plain sweep — the same
-    # executable as activity="off".
-    selective = ctx.activity == "selective" and not row_mask.all()
-    tile_active = sess._packed_tile_activity(row_mask) if selective else None
+        ident = reduce_identity(prog.reduce, prog.dtype)
+        attrs_flat = attrs.reshape(K, g.n_pad)
+        acc = jnp.full((K, g.n_pad), ident, prog.dtype)
+        row_mask = np.zeros(g.P, dtype=bool)
+        row_mask[rows] = True
+        row_active = jnp.asarray(row_mask)
+        # Selective execution: map the interval frontier onto the tile axis
+        # (a tile is active iff any source interval in its span is) and run
+        # the sweep compacted to active tiles / active streamed chunks. A
+        # full frontier short-circuits to the plain sweep — the same
+        # executable as activity="off".
+        selective = ctx.activity == "selective" and not row_mask.all()
+        tile_active = (
+            sess._packed_tile_activity(row_mask) if selective else None
+        )
     sweep, apply_all = _packed_jits(jax.default_backend() != "cpu")
     if ctx.execution == "packed_kernel":
         # Same streaming/selective drivers, fused-kernel sweep executable
@@ -1493,11 +1537,13 @@ def _iteration_packed(ctx: _RunContext, attrs, active, meters: Meters):
             ctx, attrs_flat, acc, tiles, row_active, sweep, tile_active
         )
     acc = acc.reshape(K, g.P, g.interval_size)
-    new, changed = apply_all(
-        prog, attrs, acc, ctx.aux, globals_, ctx.valid, ctx.tol,
-        aux_batched=ctx.aux_batched,
-    )
-    return new, np.asarray(changed)
+    with _TRACER.span("sweep.apply") if ctx.trace else NO_SPAN:
+        new, changed = apply_all(
+            prog, attrs, acc, ctx.aux, globals_, ctx.valid, ctx.tol,
+            aux_batched=ctx.aux_batched,
+        )
+    with _TRACER.span("sweep.sync") if ctx.trace else NO_SPAN:
+        return new, np.asarray(changed)
 
 
 def _batch_aux(prog: VertexProgram, g, kwargs_list: list[dict]) -> tuple[dict, bool]:
@@ -1642,8 +1688,14 @@ class _StagedGraph:
             if packed is None:
                 with _TRACER.span(
                     "stage_packed_host", cat="staging", mode=mode
-                ):
+                ) as span:
                     packed = self.graph.packed_sweep(mode)
+                    if _TRACER.enabled:
+                        span.set(
+                            tile_edges=int(packed.tile_edges),
+                            num_tiles=int(packed.num_tiles),
+                            padded_edge_slots=int(packed.padded_edge_slots),
+                        )
             self._packed_host[mode] = packed
         return packed
 
@@ -2869,9 +2921,10 @@ class GraphSession:
         # happens before the pins below. Per-sweep spans carry the sweep's
         # *physical* byte deltas (their sum over a fresh run equals
         # Result.meters.bytes_h2d / bytes_disk_read exactly — h2d/disk are
-        # only ever charged inside sweeps). Model-unit byte counters are
-        # published per sweep as meter deltas; the physical kinds are
-        # published at the transfer/mmap boundaries themselves.
+        # only ever charged inside sweeps) and the tiles its scans covered.
+        # Model-unit byte counters and the sweep count are published once,
+        # at run end, as meter deltas; the physical kinds are published at
+        # the transfer/mmap boundaries themselves.
         tspec = plan.trace
         obs_on = _REGISTRY.enabled
         was_tracing = _TRACER.enabled
@@ -2923,6 +2976,7 @@ class GraphSession:
                 activity=compiled.activity,
                 aux_batched=aux_batched,
                 execution=compiled.execution,
+                trace=trace_sweeps,
             )
             if compiled.execution in ("packed", "packed_kernel"):
                 iteration = _iteration_packed
@@ -2942,93 +2996,100 @@ class GraphSession:
                 wall0 = meters.wall_seconds
             ckpt = plan.checkpoint
             inj = self._injector
+            sweeps0 = sweeps
+            model0 = [getattr(meters, f) for f, _ in _OBS_MODEL_BYTES]
+            run_span = (
+                _TRACER.span("run", cat="engine", run=run_id)
+                if tracing
+                else NO_SPAN
+            )
             start = time.perf_counter()
-            for _ in range(sweeps, plan.max_iters):
-                if not active.any():
-                    break
-                # Cooperative cancellation (serving deadlines) and injected
-                # crashes both land here, on the sweep boundary — never
-                # mid-sweep, so checkpointed state is always consistent.
-                if cancel is not None:
-                    cancel(sweeps)
-                if inj is not None:
-                    inj.check("sweep", sweeps)
-                # Record the sweep's processed-interval bitmap (the union
-                # _rows_to_process acts on) before the sweep mutates `active`
-                # — this is the trace the iomodel activity terms consume.
-                if compiled.activity == "selective":
-                    activity_log.append(active.any(axis=0).copy())
-                else:
-                    activity_log.append(np.ones(g.P, dtype=bool))
-                if obs_on or trace_sweeps:
-                    s_h2d = meters.bytes_h2d
-                    s_disk = meters.bytes_disk_read
-                    s_model = [getattr(meters, f) for f, _ in _OBS_MODEL_BYTES]
-                    t_sweep = time.perf_counter()
-                attrs, active = iteration(ctx, attrs, active, meters)
-                sweeps += 1
-                meters.iterations += 1
-                if obs_on:
-                    _OBS_SWEEPS.inc()
-                    for (f, child), before in zip(_OBS_MODEL_BYTES, s_model):
-                        delta = getattr(meters, f) - before
-                        if delta:
-                            child.inc(delta)
-                if trace_sweeps:
-                    _TRACER.record(
-                        "sweep", t_sweep, time.perf_counter(), cat="engine",
-                        args={
-                            "run": run_id,
-                            "sweep": sweeps - 1,
-                            "bytes_h2d": meters.bytes_h2d - s_h2d,
-                            "bytes_disk_read": meters.bytes_disk_read - s_disk,
-                            "active_intervals": int(activity_log[-1].sum()),
-                            "intervals": int(g.P),
-                        },
-                    )
-                for m in range(K):
-                    if converged_at[m] is None and not active[m].any():
-                        converged_at[m] = sweeps
-                if ckpt is not None and sweeps % ckpt.every == 0:
-                    t_ck = time.perf_counter()
-                    self._save_sweep_snapshot(
-                        ckpt, plan, attrs, active, converged_at, sweeps,
-                        activity_log, meters,
-                        wall0 + (t_ck - start),
-                    )
-                    if tracing:
-                        _TRACER.record(
-                            "checkpoint", t_ck, time.perf_counter(),
-                            cat="engine",
-                            args={"run": run_id, "sweep": sweeps},
+            with run_span:
+                for _ in range(sweeps, plan.max_iters):
+                    if not active.any():
+                        break
+                    # Cooperative cancellation (serving deadlines) and
+                    # injected crashes both land here, on the sweep boundary
+                    # — never mid-sweep, so checkpointed state is always
+                    # consistent.
+                    if cancel is not None:
+                        cancel(sweeps)
+                    if inj is not None:
+                        inj.check("sweep", sweeps)
+                    # Record the sweep's processed-interval bitmap (the union
+                    # _rows_to_process acts on) before the sweep mutates
+                    # `active` — this is the trace the iomodel activity terms
+                    # consume.
+                    if compiled.activity == "selective":
+                        activity_log.append(active.any(axis=0).copy())
+                    else:
+                        activity_log.append(np.ones(g.P, dtype=bool))
+                    if trace_sweeps:
+                        s_h2d = meters.bytes_h2d
+                        s_disk = meters.bytes_disk_read
+                        s_tiles = ctx.tiles_swept
+                        sweep_span = _TRACER.span(
+                            "sweep", cat="engine", run=run_id, sweep=sweeps
                         )
-            end = time.perf_counter()
-            meters.wall_seconds = wall0 + (end - start)
-            if tracing:
-                _TRACER.record(
-                    "run", start, end, cat="engine",
-                    args={
-                        "run": run_id,
-                        "program": prog.name,
-                        "strategy": compiled.choice.strategy,
-                        "residency": compiled.residency,
-                        "execution": compiled.execution,
-                        "K": K,
-                        "n": int(g.n),
-                        "m": int(g.m),
-                        "P": int(g.P),
-                        "sweeps": sweeps,
-                        "bytes_h2d": meters.bytes_h2d,
-                        "bytes_disk_read": meters.bytes_disk_read,
-                        "converged": bool(not active.any()),
-                    },
-                )
-                if tspec is not None and tspec.path:
-                    _TRACER.export(tspec.path, since=mark)
+                    else:
+                        sweep_span = NO_SPAN
+                    with sweep_span:
+                        attrs, active = iteration(ctx, attrs, active, meters)
+                        if trace_sweeps:
+                            sweep_span.set(
+                                bytes_h2d=meters.bytes_h2d - s_h2d,
+                                bytes_disk_read=meters.bytes_disk_read - s_disk,
+                                tiles=ctx.tiles_swept - s_tiles,
+                                active_intervals=int(activity_log[-1].sum()),
+                                intervals=int(g.P),
+                            )
+                    sweeps += 1
+                    meters.iterations += 1
+                    for m in range(K):
+                        if converged_at[m] is None and not active[m].any():
+                            converged_at[m] = sweeps
+                    if ckpt is not None and sweeps % ckpt.every == 0:
+                        with (
+                            _TRACER.span(
+                                "checkpoint", cat="engine", run=run_id,
+                                sweep=sweeps,
+                            )
+                            if tracing
+                            else NO_SPAN
+                        ):
+                            self._save_sweep_snapshot(
+                                ckpt, plan, attrs, active, converged_at,
+                                sweeps, activity_log, meters,
+                                wall0 + (time.perf_counter() - start),
+                            )
+                end = time.perf_counter()
+                meters.wall_seconds = wall0 + (end - start)
+                if tracing:
+                    run_span.set(
+                        program=prog.name,
+                        strategy=compiled.choice.strategy,
+                        residency=compiled.residency,
+                        execution=compiled.execution,
+                        K=K,
+                        n=int(g.n),
+                        m=int(g.m),
+                        P=int(g.P),
+                        sweeps=sweeps,
+                        bytes_h2d=meters.bytes_h2d,
+                        bytes_disk_read=meters.bytes_disk_read,
+                        converged=bool(not active.any()),
+                    )
+            if tracing and tspec is not None and tspec.path:
+                _TRACER.export(tspec.path, since=mark)
         finally:
             if tracing and not was_tracing:
                 _TRACER.enabled = was_tracing
         if obs_on:
+            _OBS_SWEEPS.inc(sweeps - sweeps0)
+            for (f, child), before in zip(_OBS_MODEL_BYTES, model0):
+                delta = getattr(meters, f) - before
+                if delta:
+                    child.inc(delta)
             _OBS_RUNS.labels(
                 program=prog.name,
                 strategy=compiled.choice.strategy,

@@ -13,6 +13,8 @@ import dataclasses
 
 import numpy as np
 
+from repro.obs.trace import NO_SPAN, TRACER
+
 __all__ = [
     "EdgeList",
     "degree_and_densify",
@@ -142,39 +144,50 @@ def degree_and_densify(
 
     Vertices with no incident edge are eliminated (the paper's vertex counts
     exclude isolated vertices — Table III footnote).
+
+    Traced as ``preprocess.densify`` with the sub-spans
+    ``densify.unique_ids``, ``densify.dedup`` and ``densify.degrees``.
     """
-    src = np.asarray(src, dtype=np.int64)
-    dst = np.asarray(dst, dtype=np.int64)
-    if src.shape != dst.shape:
-        raise ValueError(f"src/dst shape mismatch: {src.shape} vs {dst.shape}")
-    if drop_self_loops:
-        keep = src != dst
-        src, dst = src[keep], dst[keep]
-        if weights is not None:
-            weights = weights[keep]
-    # Dense id assignment over the union of endpoints, sorted by raw index so
-    # that the mapping is monotone (searchsorted-able reverse mapping).
-    id_to_index, inverse = np.unique(
-        np.concatenate([src, dst]), return_inverse=True
-    )
-    m = src.shape[0]
-    src_id = inverse[:m].astype(np.int32)
-    dst_id = inverse[m:].astype(np.int32)
-    n = int(id_to_index.shape[0])
-    if dedup:
-        key = src_id.astype(np.int64) * n + dst_id
-        _, keep_idx = np.unique(key, return_index=True)
-        src_id, dst_id = src_id[keep_idx], dst_id[keep_idx]
-        if weights is not None:
-            weights = weights[keep_idx]
-    out_deg = np.bincount(src_id, minlength=n).astype(np.int32)
-    in_deg = np.bincount(dst_id, minlength=n).astype(np.int32)
-    return EdgeList(
-        src=src_id,
-        dst=dst_id,
-        n=n,
-        out_degree=out_deg,
-        in_degree=in_deg,
-        id_to_index=id_to_index,
-        weights=None if weights is None else weights.astype(np.float32),
-    )
+    tracing = TRACER.enabled
+    with TRACER.span("preprocess.densify") if tracing else NO_SPAN:
+        src = np.asarray(src, dtype=np.int64)
+        dst = np.asarray(dst, dtype=np.int64)
+        if src.shape != dst.shape:
+            raise ValueError(
+                f"src/dst shape mismatch: {src.shape} vs {dst.shape}"
+            )
+        if drop_self_loops:
+            keep = src != dst
+            src, dst = src[keep], dst[keep]
+            if weights is not None:
+                weights = weights[keep]
+        # Dense id assignment over the union of endpoints, sorted by raw
+        # index so that the mapping is monotone (searchsorted-able reverse
+        # mapping).
+        with TRACER.span("densify.unique_ids") if tracing else NO_SPAN:
+            id_to_index, inverse = np.unique(
+                np.concatenate([src, dst]), return_inverse=True
+            )
+        m = src.shape[0]
+        src_id = inverse[:m].astype(np.int32)
+        dst_id = inverse[m:].astype(np.int32)
+        n = int(id_to_index.shape[0])
+        if dedup:
+            with TRACER.span("densify.dedup") if tracing else NO_SPAN:
+                key = src_id.astype(np.int64) * n + dst_id
+                _, keep_idx = np.unique(key, return_index=True)
+                src_id, dst_id = src_id[keep_idx], dst_id[keep_idx]
+                if weights is not None:
+                    weights = weights[keep_idx]
+        with TRACER.span("densify.degrees") if tracing else NO_SPAN:
+            out_deg = np.bincount(src_id, minlength=n).astype(np.int32)
+            in_deg = np.bincount(dst_id, minlength=n).astype(np.int32)
+        return EdgeList(
+            src=src_id,
+            dst=dst_id,
+            n=n,
+            out_degree=out_deg,
+            in_degree=in_deg,
+            id_to_index=id_to_index,
+            weights=None if weights is None else weights.astype(np.float32),
+        )

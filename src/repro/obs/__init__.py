@@ -13,9 +13,10 @@ The cross-cutting layer that makes the engine's exact byte accounting
   render`; disable everything with ``REPRO_OBS=0``.
 * :data:`TRACER` — bounded ring recorder of structured spans (staging,
   each sweep with its physical byte deltas, checkpoint writes, serving
-  batch cuts), exportable as Chrome/Perfetto ``trace_event`` JSON. Off
-  by default; enable process-wide via :func:`enable_tracing` or per run
-  via the :class:`TraceSpec` plan knob.
+  batch cuts), exportable as Chrome/Perfetto ``trace_event`` JSON, and
+  mirrored as ``repro.<name>`` annotations on a JAX profile's host plane
+  while one is taken. Off by default; enable process-wide via
+  :func:`enable_tracing` or per run via the :class:`TraceSpec` plan knob.
 * :class:`TelemetryServer` — stdlib HTTP endpoint serving ``/metrics``
   and ``/healthz`` (attached to ``GraphServer`` via
   ``telemetry_port=...``).
@@ -34,6 +35,7 @@ from repro.obs.registry import (
     parse_prometheus,
 )
 from repro.obs.trace import (
+    NO_SPAN,
     Span,
     TraceSpec,
     Tracer,
@@ -49,6 +51,7 @@ __all__ = [
     "Histogram",
     "HistogramValue",
     "MetricsRegistry",
+    "NO_SPAN",
     "REGISTRY",
     "Span",
     "TelemetryServer",

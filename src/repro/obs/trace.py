@@ -19,10 +19,23 @@ the ≤2% bench budget. Enable process-wide with :func:`enable_tracing`, or
 per run with the :class:`TraceSpec` plan knob
 (``ExecutionPlan(trace=TraceSpec(path="run.json"))``), which turns the
 recorder on for that run's duration and writes its spans on completion.
+
+While tracing is on, every :meth:`Tracer.span` also enters a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>`` (with the span's
+integer args), so whenever a JAX profile is being taken the span lands on
+the profiler's host plane, on the same clock as the device ops. JAX is
+imported only when the first live span opens: the package stays
+importable without it. Hot call sites gate on the flag themselves and
+pass :data:`NO_SPAN` when it is off, so the disabled path builds neither
+a span object nor an args dict::
+
+    with TRACER.span("sweep.scan", tiles=n) if TRACER.enabled else NO_SPAN:
+        ...
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import itertools
 import json
@@ -34,6 +47,7 @@ __all__ = [
     "TraceSpec",
     "Tracer",
     "TRACER",
+    "NO_SPAN",
     "enable_tracing",
     "disable_tracing",
 ]
@@ -88,8 +102,8 @@ class Tracer:
 
     ``record``/``instant`` append unconditionally — *callers* gate on
     ``tracer.enabled`` (one branch) so the disabled path never builds an
-    args dict. The ``span`` context manager gates itself and is the
-    convenient form for non-hot call sites.
+    args dict. The ``span`` context manager gates itself; hot call sites
+    gate it too and pass :data:`NO_SPAN` when tracing is off.
     """
 
     def __init__(self, capacity: int = 65536):
@@ -147,7 +161,12 @@ class Tracer:
         self.record(name, now, now, cat=cat, tid_label=tid_label, args=args)
 
     def span(self, name: str, *, cat: str = "repro", **args):
-        """Context manager; records on exit iff the tracer is enabled."""
+        """Context manager; records on exit iff the tracer is enabled.
+
+        While live it also brackets its body in a profiler annotation
+        ``repro.<name>`` carrying the integer ``args``. ``span.set(...)``
+        adds args known only at the end (to the ring, not the profiler).
+        """
         return _SpanCtx(self, name, cat, args)
 
     # -- access / export -----------------------------------------------------
@@ -223,8 +242,26 @@ class Tracer:
         return path
 
 
+#: The null span a call site passes when tracing is off (reusable).
+NO_SPAN = contextlib.nullcontext()
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, or False without JAX
+
+
+def _annotation():
+    """The profiler annotation class, imported on the first live span."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = False
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
+
 class _SpanCtx:
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_live")
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_live", "_ann")
 
     def __init__(self, tracer, name, cat, args):
         self._tracer = tracer
@@ -233,21 +270,33 @@ class _SpanCtx:
         self._args = args
         self._t0 = 0.0
         self._live = False
+        self._ann = None
+
+    def set(self, **args) -> None:
+        """Add args known only at the end of the span (ring only)."""
+        self._args.update(args)
 
     def __enter__(self):
         self._live = self._tracer.enabled
         if self._live:
+            ann = _annotation()
+            if ann:
+                self._ann = ann(
+                    "repro." + self._name,
+                    **{k: v for k, v in self._args.items() if type(v) is int},
+                )
+                self._ann.__enter__()
             self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         if self._live:
+            t1 = time.perf_counter()
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
+                self._ann = None
             self._tracer.record(
-                self._name,
-                self._t0,
-                time.perf_counter(),
-                cat=self._cat,
-                args=self._args,
+                self._name, self._t0, t1, cat=self._cat, args=self._args
             )
         return False
 
