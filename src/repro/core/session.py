@@ -189,6 +189,11 @@ _OBS_TILES = _REGISTRY.counter(
     "Packed tiles covered by dispatched sweep scans (active tiles only "
     "under selective execution; the bucket padding is not counted)",
 )
+_OBS_MSG_TILES = _REGISTRY.counter(
+    "repro_engine_vertex_message_tiles_total",
+    "Tiles swept with one precomputed per-vertex message per edge "
+    "(unweighted programs without destination aux, XLA scan sweep)",
+)
 _OBS_RUNS = _REGISTRY.counter(
     "repro_engine_runs_total",
     "Engine runs completed",
@@ -663,7 +668,11 @@ def _fused_iteration(
 # (b) stream order folds each destination's sub-shard partials in ascending
 # source-interval order — the fold order of SPU and of the DPU/MPU
 # two-phase schedules alike — and (c) padding and inactive-row edges
-# contribute exact ⊕-identities.
+# contribute exact ⊕-identities. When the gather reads nothing per edge
+# (no weights, no destination aux) its contribution is a function of the
+# source vertex alone, so the sweep computes it once per vertex before the
+# scan — inactive sources already set to the ⊕-identity — and each edge
+# gathers that one message: the same elementwise values, bit for bit.
 # ---------------------------------------------------------------------------
 def _stack_interval_aux(aux: dict, P: int, isz: int) -> dict:
     """Reshape 1-D (n_pad,) aux leaves to (P, isz) interval rows in-trace."""
@@ -671,6 +680,103 @@ def _stack_interval_aux(aux: dict, P: int, isz: int) -> dict:
         k: (v.reshape(P, isz) if getattr(v, "ndim", 0) == 1 else v)
         for k, v in aux.items()
     }
+
+
+def _vertex_message_applies(program: VertexProgram, has_weights: bool) -> bool:
+    """Whether ``program``'s per-edge contribution depends on the source
+    vertex alone, so a sweep may gather one precomputed message per edge."""
+    return not has_weights and not program.needs_dst_aux
+
+
+def _vertex_messages(program, attrs_flat, aux, vert_active, aux_batched):
+    """(K, n_pad) per-vertex messages: ``program.gather`` over every vertex,
+    with sources in inactive intervals set to the ⊕-identity."""
+
+    def one(pv, auxq):
+        msg = program.gather(pv, None, auxq, None)
+        return jnp.where(
+            vert_active, msg, reduce_identity(program.reduce, msg.dtype)
+        )
+
+    return jax.vmap(one, in_axes=(0, _aux_axes(aux, aux_batched)))(
+        attrs_flat, aux
+    )
+
+
+def _message_contributions(program, msgs, tile):
+    """(K, T) contributions of one tile from the per-vertex messages: one
+    gather per edge, tile padding (past ``e_valid``) set to the identity."""
+    src = tile["src"]
+    live = jnp.arange(src.shape[-1]) < tile["e_valid"]
+
+    def one(m):
+        return jnp.where(
+            live, m[src], reduce_identity(program.reduce, m.dtype)
+        )
+
+    return jax.vmap(one)(msgs)
+
+
+def _edge_contributions(
+    program, attrs_flat, aux, vert_active, has_weights, aux_batched, tile
+):
+    """(K, T) contributions of one tile gathered per edge: source attribute,
+    weight, source and destination aux; padding and edges whose source
+    interval is inactive set to the identity."""
+    src = tile["src"]
+    dst = tile["dst"]
+    w = tile["weights"] if has_weights else None
+    live = (jnp.arange(src.shape[-1]) < tile["e_valid"]) & vert_active[src]
+
+    def one(pv, auxq):
+        vals = pv[src]
+        s_aux = {
+            k: (v[src] if getattr(v, "ndim", 0) == 1 else v)
+            for k, v in auxq.items()
+        }
+        d_aux = (
+            {
+                k: (v[dst] if getattr(v, "ndim", 0) == 1 else v)
+                for k, v in auxq.items()
+            }
+            if program.needs_dst_aux
+            else None
+        )
+        contrib = program.gather(vals, w, s_aux, d_aux)
+        return jnp.where(
+            live, contrib, reduce_identity(program.reduce, contrib.dtype)
+        )
+
+    return jax.vmap(one, in_axes=(0, _aux_axes(aux, aux_batched)))(
+        attrs_flat, aux
+    )
+
+
+def _fold_tile(program, contrib, tile, acc_flat):
+    """Segment-reduce a tile's (K, T) contributions by ``run_local`` and
+    scatter-fold the run partials into the (K, n_pad) accumulator."""
+    run = tile["run_local"]
+    run_dst = tile["run_dst"]
+    T = run.shape[-1]
+    segment_reduce = {
+        "sum": jax.ops.segment_sum,
+        "min": jax.ops.segment_min,
+        "max": jax.ops.segment_max,
+    }[program.reduce]
+
+    def one(c, aq):
+        with jax.named_scope("segment_reduce"):
+            red = segment_reduce(c, run, num_segments=T)
+        with jax.named_scope("scatter_fold"):
+            red = red.astype(aq.dtype)
+            fold = aq.at[run_dst]
+            if program.reduce == "sum":
+                return fold.add(red, mode="drop")
+            if program.reduce == "min":
+                return fold.min(red, mode="drop")
+            return fold.max(red, mode="drop")
+
+    return jax.vmap(one)(contrib, acc_flat)
 
 
 def _packed_sweep_impl(
@@ -685,24 +791,28 @@ def _packed_sweep_impl(
 ):
     """The gather-reduce phase of one update sweep over a tile sequence.
 
-    Each scan step processes one destination-aligned tile: gather source
-    attributes/aux by the tile's global ``src`` ids, segment-reduce the
-    contributions by ``run_local`` (the ToHub windowed partial — one
-    segment per (sub-shard, destination) run), then scatter-fold the run
-    partials into the flat accumulator at ``run_dst`` (the FromHub fold).
-    Update order within the scatter is ascending run order, i.e. exactly
-    the schedules' ascending-source-interval fold order.
+    Each scan step processes one destination-aligned tile: build the
+    tile's contributions from its global ``src`` ids, segment-reduce them
+    by ``run_local`` (the ToHub windowed partial — one segment per
+    (sub-shard, destination) run), then scatter-fold the run partials into
+    the flat accumulator at ``run_dst`` (the FromHub fold). Update order
+    within the scatter is ascending run order, i.e. exactly the schedules'
+    ascending-source-interval fold order.
 
-    Edges past ``e_valid`` (tile padding) and edges whose source interval
-    is inactive this sweep (monotone activity tracking — the (P,) row
-    mask is expanded to a per-vertex mask in-trace, so only P bools cross
-    the host→device boundary per sweep) contribute exact ⊕-identities;
-    padded run slots carry the ``n_pad`` sentinel in ``run_dst`` and are
-    dropped by the scatter. Called once over all tiles under device
-    residency, and once per streamed chunk (same executable, smaller
-    leading axis) under host residency — the scan carry composes exactly.
+    Edges whose source interval is inactive this sweep (monotone activity
+    tracking — the (P,) row mask is expanded to a per-vertex mask
+    in-trace, so only P bools cross the host→device boundary per sweep)
+    and edges past ``e_valid`` (tile padding) contribute exact
+    ⊕-identities; padded run slots carry the ``n_pad`` sentinel in
+    ``run_dst`` and are dropped by the scatter. Unweighted programs
+    without destination aux (:func:`_vertex_message_applies`) apply the
+    activity mask once per vertex, in the messages built before the scan,
+    and each step gathers one message per edge and masks only the
+    padding; the others gather attribute, aux and mask per edge. Called
+    once over all tiles under device residency, and once per streamed
+    chunk (same executable, smaller leading axis) under host residency —
+    the scan carry composes exactly.
     """
-    T = tiles["src"].shape[-1]
     n_pad = attrs_flat.shape[-1]
     # Interval mask -> per-vertex mask. A broadcast, not jnp.repeat: the
     # TPU compiler spends ~100 s constant-folding repeat's index arithmetic
@@ -711,58 +821,26 @@ def _packed_sweep_impl(
     vert_active = jnp.broadcast_to(row_active[:, None], (P, n_pad // P))
     vert_active = vert_active.reshape(n_pad)
 
-    segment_reduce = {
-        "sum": jax.ops.segment_sum,
-        "min": jax.ops.segment_min,
-        "max": jax.ops.segment_max,
-    }[program.reduce]
+    if _vertex_message_applies(program, has_weights):
+        with jax.named_scope("vertex_message"):
+            msgs = _vertex_messages(
+                program, attrs_flat, aux, vert_active, aux_batched
+            )
+        contributions = functools.partial(
+            _message_contributions, program, msgs
+        )
+    else:
+        contributions = functools.partial(
+            _edge_contributions, program, attrs_flat, aux, vert_active,
+            has_weights, aux_batched,
+        )
 
-    # The three phases carry named scopes, so the op profile and HLO dumps
+    # The phases carry named scopes, so the op profile and HLO dumps
     # attribute each fusion of the step to gather, segment reduce or fold.
     def body(carry, tile):
-        src = tile["src"]
-        dst = tile["dst"]
-        run = tile["run_local"]
-        run_dst = tile["run_dst"]
-        w = tile["weights"] if has_weights else None
         with jax.named_scope("gather"):
-            mask = (jnp.arange(T) < tile["e_valid"]) & vert_active[src]
-
-        def one(pv, aq, auxq):
-            with jax.named_scope("gather"):
-                vals = pv[src]
-                s_aux = {
-                    k: (v[src] if getattr(v, "ndim", 0) == 1 else v)
-                    for k, v in auxq.items()
-                }
-                d_aux = (
-                    {
-                        k: (v[dst] if getattr(v, "ndim", 0) == 1 else v)
-                        for k, v in auxq.items()
-                    }
-                    if program.needs_dst_aux
-                    else None
-                )
-                contrib = program.gather(vals, w, s_aux, d_aux)
-                ident = reduce_identity(program.reduce, contrib.dtype)
-                contrib = jnp.where(mask, contrib, ident)
-            with jax.named_scope("segment_reduce"):
-                red = segment_reduce(contrib, run, num_segments=T)
-            with jax.named_scope("scatter_fold"):
-                red = red.astype(aq.dtype)
-                fold = aq.at[run_dst]
-                if program.reduce == "sum":
-                    return fold.add(red, mode="drop")
-                if program.reduce == "min":
-                    return fold.min(red, mode="drop")
-                return fold.max(red, mode="drop")
-
-        return (
-            jax.vmap(one, in_axes=(0, 0, _aux_axes(aux, aux_batched)))(
-                attrs_flat, carry, aux
-            ),
-            None,
-        )
+            contrib = contributions(tile)
+        return _fold_tile(program, contrib, tile, carry), None
 
     acc_flat, _ = jax.lax.scan(body, acc_flat, tiles)
     return acc_flat
@@ -1370,9 +1448,15 @@ def _sweep_tile_slab(
 
 def _count_tiles(ctx: _RunContext, n: int) -> None:
     """Charge ``n`` tiles to the run and ``repro_engine_tiles_swept_total``
-    at the scan's dispatch site."""
+    at the scan's dispatch site, and to
+    ``repro_engine_vertex_message_tiles_total`` where the scan gathers one
+    per-vertex message per edge (the fused kernel keeps its own gather)."""
     ctx.tiles_swept += n
     _OBS_TILES.inc(n)
+    if ctx.execution != "packed_kernel" and _vertex_message_applies(
+        ctx.program, ctx.session.has_weights
+    ):
+        _OBS_MSG_TILES.inc(n)
 
 
 def _packed_host_sweep(

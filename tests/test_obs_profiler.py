@@ -9,18 +9,32 @@
   count the tiles each scan covers: all of them at a full frontier, the
   active ones under selective execution, the pinned slab plus the
   streamed chunks under host residency.
+* ``repro_engine_vertex_message_tiles_total`` counts the same tiles where
+  the scan gathers one per-vertex message per edge, and none where a
+  program's gather reads weights or destination aux.
 * The ``tiles_per_sweep`` benchmark reader, and the recorded chip trace's
   reduction, which this instrumentation must leave as it was.
 """
+import dataclasses
 import importlib.util
 import json
 from pathlib import Path
 
 import jax
+import numpy as np
 import pytest
 
-from repro.core import BFS, ExecutionPlan, GraphSession, PageRank, TraceSpec, build_dsss
+from repro.core import (
+    BFS,
+    SSSP,
+    ExecutionPlan,
+    GraphSession,
+    PageRank,
+    TraceSpec,
+    build_dsss,
+)
 from repro.core.dsss import active_tile_mask, tile_source_spans
+from repro.core.vertex_programs import ReachBackward
 from repro.graph.generators import erdos_renyi, rmat
 from repro.graph.preprocess import degree_and_densify
 from repro.obs import REGISTRY, TRACER, MetricsRegistry, disable_tracing, enable_tracing
@@ -28,6 +42,7 @@ from repro.obs import trace as trace_mod
 
 REPO = Path(__file__).resolve().parents[1]
 TILES = "repro_engine_tiles_swept_total"
+MESSAGE_TILES = "repro_engine_vertex_message_tiles_total"
 
 
 def _build(n=130, m=800, seed=7, P=4):
@@ -239,6 +254,60 @@ def test_host_residency_counts_pins_and_streamed_chunks(tracing):
     assert streamed == nt * 3
     assert [a["tiles"] for a in _sweep_spans(tracing)] == [nt] * 3
     assert REGISTRY.value(TILES) - before == nt * 3
+
+
+def _message_tile_run(case):
+    """(session, plan) of one counter case on a small graph."""
+    src, dst = erdos_renyi(130, 800, seed=7)
+    weights = None
+    if case == "sssp-weighted":
+        weights = np.random.default_rng(7).uniform(0.1, 2.0, len(src)).astype(np.float32)
+    el = degree_and_densify(src, dst, weights=weights, drop_self_loops=True)
+    g = build_dsss(el, 4)
+    if case == "pagerank":
+        return GraphSession(g), ExecutionPlan(PageRank(), max_iters=4, tol=0.0)
+    if case == "pagerank-host":
+        budget = int(g.total_edge_bytes(8) * 0.3)
+        sess = GraphSession(g, memory_budget=budget, residency="host")
+        return sess, ExecutionPlan(PageRank(), max_iters=3, tol=0.0)
+    if case in ("bfs-full", "bfs-selective"):
+        activity = "off" if case == "bfs-full" else "auto"
+        return GraphSession(g), ExecutionPlan(
+            BFS(), max_iters=g.n + 1, activity=activity, program_kwargs={"root": 0}
+        )
+    if case == "sssp-weighted":
+        return GraphSession(g), ExecutionPlan(
+            SSSP(), max_iters=g.n + 1, program_kwargs={"root": 0}
+        )
+    reach = np.zeros(g.n_pad, np.int32)
+    reach[:3] = 1
+    colors = (np.arange(g.n_pad) % 2).astype(np.int32)
+    return GraphSession(g), ExecutionPlan(
+        ReachBackward(), max_iters=g.n + 1,
+        program_kwargs={"reach": reach, "colors": colors},
+    )
+
+
+@pytest.mark.parametrize(
+    "case, engaged",
+    [
+        ("pagerank", True),
+        ("pagerank-host", True),
+        ("bfs-full", True),
+        ("bfs-selective", True),
+        ("sssp-weighted", False),
+        ("reach-backward", False),
+    ],
+)
+def test_vertex_message_tiles_count_the_message_path(case, engaged):
+    sess, plan = _message_tile_run(case)
+    tiles0, msg0 = REGISTRY.value(TILES), REGISTRY.value(MESSAGE_TILES)
+    res = sess.run(dataclasses.replace(plan, execution="packed"))
+    tiles = REGISTRY.value(TILES) - tiles0
+    assert tiles > 0
+    if case == "bfs-selective":
+        assert not all(row.all() for row in res.activity_log)  # compacted scans ran
+    assert REGISTRY.value(MESSAGE_TILES) - msg0 == (tiles if engaged else 0)
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,9 @@ residency) instead of the per-sub-shard dispatch loop. Since the adaptive
 destination-aligned tiling, host residency no longer downgrades packed
 execution — also covered here, along with the layout invariants of
 :class:`repro.core.dsss.PackedSweep` and the padding bound on power-law
-graphs.
+graphs. The per-vertex message form of the tile gather (one gather per
+edge for unweighted programs without destination aux) is held bitwise to
+the per-edge form, tile by tile.
 """
 import dataclasses
 
@@ -26,6 +28,8 @@ import pytest
 
 from repro.core import (
     BFS,
+    INF_DEPTH,
+    WCC,
     ExecutionPlan,
     GraphSession,
     NXGraphEngine,
@@ -34,6 +38,7 @@ from repro.core import (
     build_dsss,
 )
 from repro.core import session as session_mod
+from repro.core.vertex_programs import MaxLabelForward
 from repro.graph.generators import erdos_renyi, ring, zipf
 from repro.graph.preprocess import degree_and_densify
 
@@ -426,3 +431,81 @@ def test_invalid_execution_values_rejected():
         GraphSession(g, packing="diagonal")
     with pytest.raises(ValueError):
         ExecutionPlan(PageRank(), execution="warp")
+
+
+def _message_case(case, g, rng):
+    """(program, attrs (K, n_pad), aux, aux_batched, row_active) per case."""
+    P, n_pad = g.P, g.n_pad
+    full = np.ones(P, bool)
+    partial = np.arange(P) % 2 == 0
+    ranks = lambda k: rng.random((k, n_pad), dtype=np.float32)  # noqa: E731
+    if case == "pagerank-k1":
+        return PageRank(), ranks(1), PageRank().make_aux(g), False, full
+    if case == "pagerank-k3":
+        return PageRank(), ranks(3), PageRank().make_aux(g), False, full
+    if case == "ppr-reset":
+        auxes = [PageRank().make_aux(g, personalize=v) for v in (0, 7, 19)]
+        aux = {k: np.stack([np.asarray(a[k]) for a in auxes]) for k in auxes[0]}
+        return PageRank(), ranks(3), aux, True, full
+    if case == "bfs":
+        attrs = rng.integers(0, 6, (2, n_pad)).astype(np.int32)
+        attrs[rng.random((2, n_pad)) < 0.4] = INF_DEPTH
+        return BFS(), attrs, {}, False, partial
+    if case == "wcc":
+        attrs = rng.integers(0, g.n, (1, n_pad)).astype(np.int32)
+        return WCC(), attrs, {}, False, full
+    mask = (rng.random(n_pad) < 0.6).astype(np.int32)
+    attrs = rng.integers(-5, 50, (1, n_pad)).astype(np.int32)
+    return (
+        MaxLabelForward(), attrs, MaxLabelForward().make_aux(g, mask=mask),
+        False, partial,
+    )
+
+
+@pytest.mark.parametrize(
+    "case", ["pagerank-k1", "pagerank-k3", "ppr-reset", "bfs", "wcc", "max-label"]
+)
+def test_vertex_message_matches_per_edge_gather(case):
+    """Gathering one precomputed per-vertex message per edge builds the
+    same contributions, and folds the same accumulators, bit for bit, as
+    gathering attribute, aux and activity mask per edge — on every tile,
+    padded ones included, and through the whole scan."""
+    import jax.numpy as jnp
+
+    g = _graph(n=300, m=2400, seed=4, P=6)
+    prog, attrs, aux, aux_batched, row_active = _message_case(
+        case, g, np.random.default_rng(1)
+    )
+    assert session_mod._vertex_message_applies(prog, has_weights=False)
+    packed = g.packed_sweep("adaptive")
+    T = packed.src.shape[-1]
+    assert (packed.e_valid < T).any()  # some tile carries padding
+    tiles = {
+        k: jnp.asarray(v)
+        for k, v in session_mod._packed_host_chunk(
+            packed, 0, packed.num_tiles, False
+        ).items()
+    }
+    attrs = jnp.asarray(attrs, prog.dtype)
+    aux = {k: jnp.asarray(v) for k, v in aux.items()}
+    vert_active = jnp.asarray(np.repeat(row_active, g.n_pad // g.P))
+    msgs = session_mod._vertex_messages(
+        prog, attrs, aux, vert_active, aux_batched
+    )
+    ident = session_mod.reduce_identity(prog.reduce, prog.dtype)
+    acc_msg = acc_edge = jnp.full(attrs.shape, ident, prog.dtype)
+    for t in range(packed.num_tiles):
+        tile = {k: v[t] for k, v in tiles.items()}
+        by_msg = session_mod._message_contributions(prog, msgs, tile)
+        by_edge = session_mod._edge_contributions(
+            prog, attrs, aux, vert_active, False, aux_batched, tile
+        )
+        np.testing.assert_array_equal(np.asarray(by_msg), np.asarray(by_edge))
+        acc_msg = session_mod._fold_tile(prog, by_msg, tile, acc_msg)
+        acc_edge = session_mod._fold_tile(prog, by_edge, tile, acc_edge)
+        np.testing.assert_array_equal(np.asarray(acc_msg), np.asarray(acc_edge))
+    swept = session_mod._packed_sweep_impl(
+        prog, attrs, jnp.full(attrs.shape, ident, prog.dtype), aux, tiles,
+        jnp.asarray(row_active), has_weights=False, aux_batched=aux_batched,
+    )
+    np.testing.assert_array_equal(np.asarray(swept), np.asarray(acc_edge))
