@@ -47,6 +47,7 @@ import numpy as np
 
 from repro.core.dsss import DSSSGraph, PackedSweep, next_bucket
 from repro.obs.registry import REGISTRY as _REGISTRY
+from repro.obs.trace import TRACER as _TRACER
 
 _OBS_READ_RETRIES = _REGISTRY.counter(
     "repro_storage_read_retries_total",
@@ -678,7 +679,8 @@ def open_dsss(
     :class:`DegradedReadError` when they stay bad (see
     :meth:`DSSSStore.ensure_segment`).
     """
-    return DSSSStore(path, verify=verify, read_policy=read_policy)
+    with _TRACER.span("store.open", verify=int(verify)):
+        return DSSSStore(path, verify=verify, read_policy=read_policy)
 
 
 def verify_dsss(path: str) -> DSSSStore:
@@ -765,35 +767,36 @@ def write_dsss(graph: DSSSGraph, path: str, *, packing: str = "auto") -> DSSSSto
     """
     if packing == "auto":
         packing = "subshard" if graph.src_sorted else "adaptive"
-    w = StoreWriter(path)
-    try:
-        meta = _base_meta(graph)
-        w.add_array("offsets", graph.offsets)
-        w.add_array("hub_offsets", graph.hub_offsets)
-        w.add_array("out_degree", graph.out_degree)
-        w.add_array("in_degree", graph.in_degree)
-        w.add_array("id_to_index", np.asarray(graph.edgelist.id_to_index, np.int64))
-        w.add_array("src", graph.src)
-        w.add_array("dst", graph.dst)
-        if graph.weights is not None:
-            w.add_array("weights", graph.weights)
-        w.add_array("hub_dst_flat", graph.hub_dst_flat)
-        w.add_array("hub_inv_flat", graph.hub_inv_flat)
-        blocks = graph.host_blocks()
-        meta["num_blocks"] = len(blocks)
-        _write_blocks(w, blocks)
-        if packing is not None:
-            packed = graph.packed_sweep(packing)
-            meta["packing"] = packed.mode
-            meta["tile_edges"] = packed.tile_edges
-            meta["num_tiles"] = packed.num_tiles
-            _write_packed(w, packed)
-        else:
-            meta["packing"] = None
-        w.close(meta)
-    except BaseException:
-        w.abort()
-        raise
+    with _TRACER.span("store.write", m=int(graph.m)):
+        w = StoreWriter(path)
+        try:
+            meta = _base_meta(graph)
+            w.add_array("offsets", graph.offsets)
+            w.add_array("hub_offsets", graph.hub_offsets)
+            w.add_array("out_degree", graph.out_degree)
+            w.add_array("in_degree", graph.in_degree)
+            w.add_array("id_to_index", np.asarray(graph.edgelist.id_to_index, np.int64))
+            w.add_array("src", graph.src)
+            w.add_array("dst", graph.dst)
+            if graph.weights is not None:
+                w.add_array("weights", graph.weights)
+            w.add_array("hub_dst_flat", graph.hub_dst_flat)
+            w.add_array("hub_inv_flat", graph.hub_inv_flat)
+            blocks = graph.host_blocks()
+            meta["num_blocks"] = len(blocks)
+            _write_blocks(w, blocks)
+            if packing is not None:
+                packed = graph.packed_sweep(packing)
+                meta["packing"] = packed.mode
+                meta["tile_edges"] = packed.tile_edges
+                meta["num_tiles"] = packed.num_tiles
+                _write_packed(w, packed)
+            else:
+                meta["packing"] = None
+            w.close(meta)
+        except BaseException:
+            w.abort()
+            raise
     return DSSSStore(path)
 
 
