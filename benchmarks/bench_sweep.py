@@ -7,7 +7,8 @@ P the run is dispatch-bound. This benchmark measures:
 * **Uniform section** (Erdős–Rényi, P ∈ {8, 16, 32}, device residency,
   PageRank): per-sweep wall seconds and jitted-primitive dispatches per
   sweep for both execution modes (counted by wrapping the session's jit
-  entry points), with bit-identity and meter equality asserted per row.
+  entry points), with attribute agreement (within :data:`SUM_RTOL` /
+  :data:`SUM_ATOL`) and meter equality asserted per row.
 * **Power-law section** (Zipf + R-MAT, P ∈ {16, 32} — the skew regime
   NXgraph §V targets): padded-edge ratio and per-sweep wall of the legacy
   one-tile-per-sub-shard packing vs. adaptive destination-aligned tiles,
@@ -23,8 +24,9 @@ P the run is dispatch-bound. This benchmark measures:
 
 * **Kernel section** (``execution="packed_kernel"`` vs ``"packed"`` on
   the same tiles): per-sweep wall + dispatch counts of the fused Pallas
-  sweep against the XLA scan, asserting bit-identical attrs, identical
-  meters, and exactly one fused ``pallas_call`` dispatch per sweep.
+  sweep against the XLA scan, asserting attrs within the sum tolerance,
+  identical meters, and exactly one fused ``pallas_call`` dispatch per
+  sweep.
   Off-TPU the kernel runs in interpret mode, so its wall number is a
   correctness-path cost, not a speed claim — the claim is the dispatch
   shape and the bits.
@@ -137,6 +139,17 @@ class DispatchCounter:
         return False
 
 
+# PageRank across backends: the scan folds each edge straight into its
+# destination, per_block and packed_kernel fold run partials, so float32
+# sums agree to rounding, not bitwise. Same-backend rows stay bitwise.
+SUM_RTOL = 1e-5
+SUM_ATOL = 1e-8
+
+
+def assert_sums_match(expected, actual):
+    np.testing.assert_allclose(actual, expected, rtol=SUM_RTOL, atol=SUM_ATOL)
+
+
 def bench_one(session, strategy, execution, iters):
     plan = ExecutionPlan(
         PageRank(), strategy=strategy, max_iters=iters, tol=0.0, execution=execution
@@ -182,7 +195,7 @@ def uniform_section(report, args):
                     f"{r['per_sweep_seconds'] * 1e3:8.2f} ms/sweep, "
                     f"{r['dispatches_per_sweep']:7.1f} dispatches/sweep"
                 )
-            np.testing.assert_array_equal(
+            assert_sums_match(
                 rows["per_block"].pop("attrs"), rows["packed"].pop("attrs")
             )
             m_pb = dataclasses.asdict(rows["per_block"].pop("meters"))
@@ -200,7 +213,7 @@ def uniform_section(report, args):
             print(
                 f"  {strategy:>4}   speedup: {speedup:5.1f}x wall, "
                 f"{dispatch_ratio:5.1f}x fewer dispatches "
-                f"(bit-identical, meters identical)"
+                f"(attrs match, meters identical)"
             )
             for execution in ("per_block", "packed"):
                 report["results"].append({"P": P, **rows[execution]})
@@ -276,7 +289,7 @@ def powerlaw_section(report, args):
                     f"h2d {r['h2d_per_sweep'] / 1e6:6.2f} MB/sweep, "
                     f"{r['dispatches_per_sweep']:6.1f} dispatches/sweep"
                 )
-            np.testing.assert_array_equal(
+            assert_sums_match(
                 host_rows["per_block"]["attrs"], host_rows["packed"]["attrs"]
             )
             # Host ≡ device bit-identity, at matching sweep counts (the
@@ -305,7 +318,7 @@ def powerlaw_section(report, args):
             print(
                 f"  adaptive vs subshard: {row['device_packing_wall_speedup']:.2f}x; "
                 f"packed-host vs per-block-host: {row['host_wall_speedup']:.2f}x "
-                "(bit-identical, model meters identical)"
+                "(attrs match, model meters identical)"
             )
             report["powerlaw"].append(row)
 
@@ -407,8 +420,8 @@ def kernel_section(report, args):
 
     Both executables are driven through the identical session machinery
     (same staging, same streaming, same apply), so every row asserts
-    bit-identical attrs and fully identical meters — including physical
-    fields — and that the kernel mode dispatched exactly one fused
+    attrs within the sum tolerance and fully identical meters — including
+    physical fields — and that the kernel mode dispatched exactly one fused
     ``pallas_call`` per update sweep with the same total dispatch count
     as the scan. Off-TPU the kernel runs under the Pallas interpreter,
     so wall seconds compare a debugging path against compiled XLA; on
@@ -433,7 +446,7 @@ def kernel_section(report, args):
                 f"{r['dispatches_per_sweep']:5.1f} dispatches/sweep "
                 f"({r['fused_dispatches_per_sweep']:.1f} fused)"
             )
-        np.testing.assert_array_equal(
+        assert_sums_match(
             rows["packed"].pop("attrs"), rows["packed_kernel"].pop("attrs")
         )
         m_scan = dataclasses.asdict(rows["packed"].pop("meters"))
@@ -457,11 +470,11 @@ def kernel_section(report, args):
             "fused_dispatches_per_sweep": rows["packed_kernel"][
                 "fused_dispatches_per_sweep"
             ],
-            "bit_identical": True,
+            "attrs_match": True,
             "meters_identical": True,
         }
         print(
-            f"kernel {strategy:>4}   parity: bit-identical, meters identical, "
+            f"kernel {strategy:>4}   parity: attrs match, meters identical, "
             f"{row['fused_dispatches_per_sweep']:.1f} fused dispatch/sweep "
             f"({kernel_backend})"
         )
@@ -492,9 +505,9 @@ def main(argv=None):
     )
     ap.add_argument(
         "--assert-kernel-parity", action="store_true",
-        help="fail (exit 1) unless every kernel-section row is "
-        "bit-identical and meter-identical to the scan with exactly one "
-        "fused dispatch per sweep",
+        help="fail (exit 1) unless every kernel-section row matches the "
+        "scan (attrs within the sum tolerance, meters identical) with "
+        "exactly one fused dispatch per sweep",
     )
     ap.add_argument(
         "--out",
@@ -525,7 +538,7 @@ def main(argv=None):
     kernel_section(report, args)
     if args.assert_kernel_parity:
         for row in report["kernel"]:
-            assert row["bit_identical"] and row["meters_identical"], (
+            assert row["attrs_match"] and row["meters_identical"], (
                 f"kernel {row['strategy']} P={row['P']}: parity broken"
             )
             assert row["fused_dispatches_per_sweep"] == 1.0, (

@@ -166,12 +166,12 @@ class PackedSweep:
     sharing a destination, i.e. exactly one hub slot. Large sub-shards
     therefore split across tiles and small consecutive sub-shards coalesce
     into shared tiles, but a destination's per-sub-shard edge run is never
-    divided, so its partial ⊕ is computed over the same values in the same
-    order as the per-block executor's segment reduce — bit-identity for
-    float ``sum`` programs is preserved with near-uniform tile occupancy
-    (``padding_ratio`` stays small on power-law graphs instead of being
-    bound by the largest sub-shard). ``tile_edges`` is chosen per graph to
-    minimise total padded slots (see :func:`choose_tile_edges`).
+    divided, so a run-partial reduce (the per-block executor's, and the
+    fused kernel's over ``run_local``) sees whole runs, with near-uniform
+    tile occupancy (``padding_ratio`` stays small on power-law graphs
+    instead of being bound by the largest sub-shard). ``tile_edges`` is
+    chosen per graph to minimise total padded slots (see
+    :func:`choose_tile_edges`).
 
     **mode="subshard"** reproduces the legacy one-tile-per-sub-shard
     packing (tiles never cross or split sub-shards, ``tile_edges`` = the
@@ -181,41 +181,37 @@ class PackedSweep:
     destination's edges are not contiguous and only whole-sub-shard
     windows group them correctly.
 
-    **Execution schema** (what the compiled scan consumes, per tile):
+    **Execution schema** (per tile):
 
     * ``src`` / ``dst`` — global endpoint ids (vertex id == padded
       position, since intervals are the contiguous ranges
       ``[i·interval_size, …)``): the scan gathers attributes and aux
-      directly from the flat ``(n_pad,)`` arrays, so a tile needs no
-      single source/destination interval and coalescing is free.
+      directly from the flat ``(n_pad,)`` arrays and scatters each edge's
+      contribution into the flat accumulator at ``dst`` (padding past
+      ``e_valid`` redirected to the ``n_pad`` drop sentinel), so a tile
+      needs no single source/destination interval and coalescing is free.
     * ``run_local`` — per-edge hub slot *within the tile's slot window*
       (global hub slot − ``base_slot``): the per-tile segment reduce over
       ``run_local`` is precisely the ToHub windowed-partial formulation of
       ``kernels/dsss_spmv.py``, which is why tiles are also valid Pallas
       kernel inputs (:func:`repro.kernels.ops.prepare_from_packed_tile`).
+      The scan does not read it; the fused kernel does.
     * ``run_dst`` — per run-slot global destination id (``n_pad`` sentinel
-      past ``u``): the FromHub fold scatters the ≤ ``tile_edges`` run
-      partials into the flat accumulator. A coalesced tile that wraps a
-      whole row cycle can hold two runs with the *same* destination (from
-      different source intervals), making the scatter carry duplicate
-      indices; the ascending-``i`` fold order then relies on the scatter
-      applying updates in index order. XLA serialises conflicting scatter
-      updates in order on CPU and TPU — the same assumption every
-      ``jax.ops.segment_*`` fold in this codebase (per-block path
-      included) already makes — but it is implementation-defined on GPU,
-      where float-``sum`` bit-identity would weaken to
-      re-association-level equality in exactly those tiles (min/max are
-      order-free either way).
+      past ``u``): the fused kernel's FromHub fold scatters the ≤
+      ``tile_edges`` run partials into the flat accumulator. The scan
+      does not read it.
     * ``e_valid`` — real edges; trailing padding is masked to exact
       ⊕-identities.
 
-    Bit-identity with the per-block executor holds because (a) runs are
-    never split, (b) the stream order folds every destination's sub-shard
-    partials in ascending source-interval order — the fold order of SPU
-    *and* of the DPU/MPU two-phase schedules (deferred-direct ``i < Q``
-    ascending, then hub folds ``i ≥ Q`` ascending), and (c) a sub-shard's
-    hub partial is bitwise equal to its direct segment-reduce because
-    destination-sorting gives both the same per-destination fold order.
+    Fold order. The scan folds each destination's in-edges left to right
+    in the tile stream's order: XLA serialises conflicting scatter
+    updates in order on CPU and TPU (implementation-defined on GPU).
+    The per-block executor and the fused kernel fold run partials in
+    ascending source-interval order instead. Min and max are order-free,
+    so every backend gives bit-identical results; a float ``sum`` is
+    reassociated across backends and agrees to float32 rounding. One
+    backend run twice over the same tiles, whatever the residency or
+    chunking, folds in the same order and is bit-identical.
 
     ``src_interval`` / ``dst_interval`` / ``base_slot`` / ``row_offset`` /
     ``u`` are the per-tile metadata (intervals of the first edge, global

@@ -763,28 +763,30 @@ def _edge_contributions(
 
 
 def _fold_tile(program, contrib, tile, acc_flat):
-    """Segment-reduce a tile's (K, T) contributions by ``run_local`` and
-    scatter-fold the run partials into the (K, n_pad) accumulator."""
-    run = tile["run_local"]
-    run_dst = tile["run_dst"]
-    T = run.shape[-1]
-    segment_reduce = {
-        "sum": jax.ops.segment_sum,
-        "min": jax.ops.segment_min,
-        "max": jax.ops.segment_max,
-    }[program.reduce]
+    """Scatter-fold a tile's (K, T) contributions into the (K, n_pad)
+    accumulator at each edge's global ``dst``: one indexed pass per edge.
+
+    Padding slots (past ``e_valid``) index the ``n_pad`` sentinel and are
+    dropped, so they leave the accumulator bit-untouched. Updates apply in
+    slot order (XLA serialises conflicting scatter updates in order on
+    CPU and TPU), so a destination's float sum is a left fold of its
+    in-edges in the tile stream's order: a reassociation of the per-block
+    executor's run-partial fold, equal to it for min and max.
+    """
+    n_pad = acc_flat.shape[-1]
+    dst = tile["dst"]
+    live = jnp.arange(dst.shape[-1]) < tile["e_valid"]
+    dst = jnp.where(live, dst, n_pad)
 
     def one(c, aq):
-        with jax.named_scope("segment_reduce"):
-            red = segment_reduce(c, run, num_segments=T)
         with jax.named_scope("scatter_fold"):
-            red = red.astype(aq.dtype)
-            fold = aq.at[run_dst]
+            fold = aq.at[dst]
+            c = c.astype(aq.dtype)
             if program.reduce == "sum":
-                return fold.add(red, mode="drop")
+                return fold.add(c, mode="drop")
             if program.reduce == "min":
-                return fold.min(red, mode="drop")
-            return fold.max(red, mode="drop")
+                return fold.min(c, mode="drop")
+            return fold.max(c, mode="drop")
 
     return jax.vmap(one)(contrib, acc_flat)
 
@@ -802,23 +804,27 @@ def _packed_sweep_impl(
     """The gather-reduce phase of one update sweep over a tile sequence.
 
     Each scan step processes one destination-aligned tile: build the
-    tile's contributions from its global ``src`` ids, segment-reduce them
-    by ``run_local`` (the ToHub windowed partial — one segment per
-    (sub-shard, destination) run), then scatter-fold the run partials into
-    the flat accumulator at ``run_dst`` (the FromHub fold). Update order
-    within the scatter is ascending run order, i.e. exactly the schedules'
-    ascending-source-interval fold order.
+    tile's contributions from its global ``src`` ids, then scatter-fold
+    them straight into the flat accumulator at their global ``dst``
+    (:func:`_fold_tile`), one indexed pass per edge. The scan reads
+    neither ``run_local`` nor ``run_dst``. Min and max results equal the
+    per-block executor's bit for bit; a float sum folds each
+    destination's in-edges left to right in the tile stream's order, a
+    reassociation of the per-block run-partial fold (equal up to float32
+    rounding, not bitwise). Every run of this executable folds in the
+    same order, so residency tiers, chunking, selective scans and resume
+    stay bit-identical to one another.
 
     Edges whose source interval is inactive this sweep (monotone activity
     tracking — the (P,) row mask is expanded to a per-vertex mask
     in-trace, so only P bools cross the host→device boundary per sweep)
-    and edges past ``e_valid`` (tile padding) contribute exact
-    ⊕-identities; padded run slots carry the ``n_pad`` sentinel in
-    ``run_dst`` and are dropped by the scatter. Unweighted programs
-    without destination aux (:func:`_vertex_message_applies`) apply the
-    activity mask once per vertex, in the messages built before the scan,
-    and each step gathers one message per edge and masks only the
-    padding; the others gather attribute, aux and mask per edge. Called
+    contribute exact ⊕-identities; edges past ``e_valid`` (tile padding)
+    index the ``n_pad`` sentinel and are dropped by the scatter.
+    Unweighted programs without destination aux
+    (:func:`_vertex_message_applies`) apply the activity mask once per
+    vertex, in the messages built before the scan, and each step gathers
+    one message per edge and masks only the padding; the others gather
+    attribute, aux and mask per edge. Called
     once over all tiles under device residency, and once per streamed
     chunk (same executable, smaller leading axis) under host residency —
     the scan carry composes exactly.
@@ -846,7 +852,7 @@ def _packed_sweep_impl(
         )
 
     # The phases carry named scopes, so the op profile and HLO dumps
-    # attribute each fusion of the step to gather, segment reduce or fold.
+    # attribute each fusion of the step to the gather or the fold.
     def body(carry, tile):
         with jax.named_scope("gather"):
             contrib = contributions(tile)

@@ -21,10 +21,15 @@ end to end —
              → FromHub scatter of run partials into the accumulator at
                ``run_dst``
 
-Bit-identity contract (the acceptance gate of the ``packed_kernel``
-execution backend): results must equal ``_packed_sweep_impl``'s
-(``core/session.py``) *bitwise*, which pins down the floating-point fold
-order exactly:
+Fold-order contract (the acceptance gate of the ``packed_kernel``
+execution backend): results must equal
+:func:`repro.kernels.ref.packed_sweep_update_ref` *bitwise*, the
+run-partial fold of the per-block executor. Against the XLA scan
+(``_packed_sweep_impl``, ``core/session.py``), which scatters each edge
+straight into the accumulator at its ``dst``, min/max results are
+bitwise equal and float sums agree to float32 rounding (a reassociation
+of a destination's in-edges). The reference pins the floating-point
+fold order exactly:
 
 * the per-run partial must be the **ascending-edge-order** left fold —
   what XLA's in-order scatter-add gives ``jax.ops.segment_sum``. A one-hot

@@ -1,23 +1,27 @@
 """The fused Pallas kernel backend (``execution="packed_kernel"``) parity suite.
 
-The kernel path's acceptance contract is the same strict one the packed
-scan passed in tests/test_packed_sweep.py, now three-way: for every
-native schedule (SPU/DPU/MPU), every program family (float-sum /
-int-min / weighted float-min), every residency (device / host / disk)
-and both activity modes, interpret-mode kernel results must be
-**bit-identical** and the model ``Meters`` **field-identical** to both
-``per_block`` and ``packed`` — while actually dispatching the fused
-``pallas_call`` (never the scan, never the per-block primitives).
+The kernel path's acceptance contract is the one the packed scan passes
+in tests/test_packed_sweep.py, three-way: for every native schedule
+(SPU/DPU/MPU), every program family (float-sum / int-min / weighted
+float-min), every residency (device / host / disk) and both activity
+modes, interpret-mode kernel results must equal ``per_block`` and
+``packed`` — while actually dispatching the fused ``pallas_call`` (never
+the scan, never the per-block primitives). The model ``Meters``,
+``iterations`` and ``converged`` must be identical.
 
-The kernel reproduces the scan's floating-point fold orders exactly
-(ascending-edge-order windowed sum fold, ascending-run-order hub
-scatter; see ``kernels/packed_sweep.py``), which is what makes bitwise —
-not approximate — equality the right assertion.
+Min and max results must be bit-identical. Float sums (PageRank, PPR)
+must agree within ``rtol=1e-5``, ``atol=1e-8``
+(``tests/_fold_contract.py``): the kernel folds a destination's run
+partials (ascending-edge-order windowed sum fold, ascending-run-order
+hub scatter; see ``kernels/packed_sweep.py``) while the scan folds each
+edge straight into its destination, a float32 reassociation of the
+destination's in-edges.
 """
 import dataclasses
 
 import numpy as np
 import pytest
+from _fold_contract import assert_attrs_match
 
 from repro.core import (
     BFS,
@@ -69,8 +73,8 @@ def _meters_dict(meters, model_only=False):
     return d
 
 
-def _assert_equivalent(ref, kern, model_only=False):
-    np.testing.assert_array_equal(ref.attrs, kern.attrs)
+def _assert_equivalent(ref, kern, reduce, model_only=False):
+    assert_attrs_match(ref.attrs, kern.attrs, reduce)
     assert ref.iterations == kern.iterations
     assert ref.converged == kern.converged
     assert _meters_dict(ref.meters, model_only) == _meters_dict(
@@ -125,10 +129,12 @@ def test_three_way_parity(
     # vs per_block: model meters always agree; physical fields describe
     # different data paths (per-block streams blocks, packed streams tile
     # chunks), so they are compared model-only off-device.
-    _assert_equivalent(pb, kn, model_only=residency != "device")
+    _assert_equivalent(
+        pb, kn, prog_cls.reduce, model_only=residency != "device"
+    )
     # vs packed: same tile streaming/selective machinery drives both, so
     # under every residency even the physical fields must coincide.
-    _assert_equivalent(pk, kn)
+    _assert_equivalent(pk, kn, prog_cls.reduce)
 
 
 def test_kernel_path_actually_runs(monkeypatch):
@@ -216,7 +222,7 @@ def test_src_sorted_subshard_tiles_parity():
     plan = dict(strategy="spu", max_iters=4, tol=0.0)
     pk = sess.run(ExecutionPlan(PageRank(), execution="packed", **plan))
     kn = sess.run(ExecutionPlan(PageRank(), execution="packed_kernel", **plan))
-    _assert_equivalent(pk, kn)
+    _assert_equivalent(pk, kn, PageRank.reduce)
 
 
 def test_batched_queries_and_stacked_aux():
@@ -241,7 +247,7 @@ def test_batched_queries_and_stacked_aux():
             )
         assert out["packed"].fused and out["packed_kernel"].fused
         for a, b in zip(out["packed"].results, out["packed_kernel"].results):
-            _assert_equivalent(a, b)
+            _assert_equivalent(a, b, prog_factory.reduce)
 
     batch(BFS, [{"root": r} for r in (0, 7, 33)], strategy="dpu")
     rng = np.random.default_rng(0)
@@ -255,7 +261,8 @@ def test_batched_queries_and_stacked_aux():
 
 def test_ppr_batch_kernel_parity():
     """Personalized PageRank point queries (differing reset vectors →
-    vmap-stacked aux) fuse and match the scan backend bitwise."""
+    vmap-stacked aux) fuse and match the scan backend within the sum
+    tolerance."""
     g = _graph(seed=9)
     sess = GraphSession(g)
     seeds = (0, 5, 41)
@@ -273,7 +280,7 @@ def test_ppr_batch_kernel_parity():
     bk = sess.run_batch(plans("packed_kernel"))
     assert bp.fused and bk.fused
     for a, b in zip(bp.results, bk.results):
-        _assert_equivalent(a, b)
+        _assert_equivalent(a, b, PageRank.reduce)
 
 
 def test_invalid_execution_values_still_rejected():

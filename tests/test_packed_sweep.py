@@ -1,11 +1,16 @@
 """Tile-packed compiled sweeps vs. the per-block executor.
 
-The packed path's contract is strict: for every native schedule
-(SPU/DPU/MPU), every program family (sum / min on weighted+unweighted
-graphs), both residencies (device-staged and host-streamed) and batched
-K > 1 runs, it must produce
+The packed path's contract: for every native schedule (SPU/DPU/MPU),
+every program family (sum / min on weighted+unweighted graphs), both
+residencies (device-staged and host-streamed) and batched K > 1 runs, it
+must produce
 
-  * bit-identical attributes and outputs, and
+  * bit-identical attributes and outputs for min and max programs, and
+    for float sums (PageRank) attributes within ``rtol=1e-5``,
+    ``atol=1e-8``: the scan folds each edge straight into its
+    destination while the per-block executor folds run partials, a
+    float32 reassociation of a destination's in-edges
+    (``tests/_fold_contract.py``), and
   * field-for-field identical *model* ``Meters`` (edges, blocks, every
     modelled byte counter) — the physical fields (``wall_seconds``,
     ``bytes_h2d``, ``peak_device_graph_bytes``) describe whichever data
@@ -19,12 +24,15 @@ execution — also covered here, along with the layout invariants of
 :class:`repro.core.dsss.PackedSweep` and the padding bound on power-law
 graphs. The per-vertex message form of the tile gather (one gather per
 edge for unweighted programs without destination aux) is held bitwise to
-the per-edge form, tile by tile.
+the per-edge form, tile by tile, and the scan's step is held to one
+scatter.
 """
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
+from _fold_contract import assert_attrs_match
 
 from repro.core import (
     BFS,
@@ -78,8 +86,8 @@ def _meters_dict(meters, model_only=False):
     return d
 
 
-def _assert_equivalent(res_pb, res_pk, model_only=False):
-    np.testing.assert_array_equal(res_pb.attrs, res_pk.attrs)
+def _assert_equivalent(res_pb, res_pk, reduce, model_only=False):
+    assert_attrs_match(res_pb.attrs, res_pk.attrs, reduce)
     assert res_pb.iterations == res_pk.iterations
     assert res_pb.converged == res_pk.converged
     assert _meters_dict(res_pb.meters, model_only) == _meters_dict(
@@ -114,7 +122,9 @@ def test_bit_identity_and_meters(
     )
     # Model meters agree always; the physical fields additionally agree
     # under device residency (neither path streams: h2d 0, peak = total).
-    _assert_equivalent(pb, pk, model_only=(residency == "host"))
+    _assert_equivalent(
+        pb, pk, prog_cls.reduce, model_only=(residency == "host")
+    )
     assert pk.meters.edges_processed > 0
     if residency == "host":
         assert pb.meters.bytes_h2d > 0 and pk.meters.bytes_h2d > 0
@@ -237,7 +247,7 @@ def test_activity_skipping_matches_per_block():
                 program_kwargs={"root": 0},
             )
         )
-        _assert_equivalent(pb, pk)
+        _assert_equivalent(pb, pk, BFS.reduce)
         assert pk.meters.blocks_skipped > 0  # the ring really does skip rows
 
 
@@ -321,7 +331,7 @@ def test_engine_shim_execution_knob():
     assert pb.execution == "per_block" and pk.execution == "packed"
     r_pb = pb.run(max_iters=5, tol=0.0)
     r_pk = pk.run(max_iters=5, tol=0.0)
-    _assert_equivalent(r_pb, r_pk)
+    _assert_equivalent(r_pb, r_pk, PageRank.reduce)
     with pytest.raises(ValueError, match="packing"):
         NXGraphEngine(g, PageRank(), packing="subshard", session=sess)
 
@@ -376,7 +386,7 @@ def test_src_sorted_requires_subshard_packing():
         ExecutionPlan(PageRank(), strategy="spu", max_iters=4, tol=0.0,
                       execution="packed")
     )
-    _assert_equivalent(pb, pk)
+    _assert_equivalent(pb, pk, PageRank.reduce)
 
 
 def test_kernel_operands_from_packed_tile():
@@ -509,3 +519,86 @@ def test_vertex_message_matches_per_edge_gather(case):
         jnp.asarray(row_active), has_weights=False, aux_batched=aux_batched,
     )
     np.testing.assert_array_equal(np.asarray(swept), np.asarray(acc_edge))
+
+
+def _scatters(jaxpr) -> int:
+    """Scatter equations in ``jaxpr``, nested calls included."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name.startswith("scatter")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _scatters(sub)
+    return n
+
+
+@pytest.mark.parametrize("prog_cls", [PageRank, BFS])
+def test_scan_step_folds_with_one_scatter(prog_cls):
+    """Each scan step folds its tile with one scatter into the
+    accumulator, and the lowered sweep holds no other scatter."""
+    import jax.numpy as jnp
+
+    g = _graph(seed=3)
+    prog = prog_cls()
+    packed = g.packed_sweep("adaptive")
+    tiles = {
+        k: jnp.asarray(v)
+        for k, v in session_mod._packed_host_chunk(
+            packed, 0, packed.num_tiles, False
+        ).items()
+    }
+    aux = {k: jnp.asarray(v) for k, v in prog.make_aux(g).items()}
+    attrs = jnp.zeros((1, g.n_pad), prog.dtype)
+    args = (prog, attrs, attrs, aux, tiles, jnp.ones(g.P, bool), False)
+    closed = jax.make_jaxpr(
+        session_mod._packed_sweep_impl, static_argnums=(0, 6)
+    )(*args)
+    (scan,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+    assert _scatters(scan.params["jaxpr"].jaxpr) == 1
+    assert _scatters(closed.jaxpr) == 1
+    lowered = jax.jit(
+        session_mod._packed_sweep_impl,
+        static_argnames=("program", "has_weights"),
+    ).lower(*args[:6], has_weights=False)
+    assert lowered.as_text().count('"stablehlo.scatter"(') == 1
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
+@pytest.mark.parametrize("label", ["bfs", "sssp", "wcc", "max-label"])
+def test_min_max_programs_bitwise_equal_per_block(label, strategy):
+    """Min and max are order-free: the one-scatter fold leaves BFS, SSSP,
+    WCC and max-label results bit for bit equal to the per-block
+    executor's under every schedule."""
+    src, dst = erdos_renyi(150, 900, seed=11)
+    w = np.random.default_rng(11).uniform(0.1, 2.0, len(src)).astype(np.float32)
+    el = degree_and_densify(
+        src, dst, weights=w if label == "sssp" else None, drop_self_loops=True
+    )
+    if label == "wcc":
+        el = el.symmetrized()
+    g = build_dsss(el, 5)
+    prog, kw = {
+        "bfs": (BFS(), {"root": 0}),
+        "sssp": (SSSP(), {"root": 0}),
+        "wcc": (WCC(), {}),
+        "max-label": (
+            MaxLabelForward(),
+            {"mask": np.random.default_rng(2).random(g.n) < 0.5},
+        ),
+    }[label]
+    sess = _session(g, "device")
+    if strategy == "mpu":
+        choice = sess.compile(ExecutionPlan(prog, strategy="mpu")).choice
+        assert 0 < choice.Q < g.P, "budget must exercise the hub split"
+
+    def run(execution):
+        return sess.run(
+            ExecutionPlan(
+                prog, strategy=strategy, execution=execution,
+                max_iters=g.n + 1, program_kwargs=kw,
+            )
+        )
+
+    pb, pk = run("per_block"), run("packed")
+    np.testing.assert_array_equal(pb.attrs, pk.attrs)
+    assert pb.iterations == pk.iterations
+    assert pb.converged and pk.converged
