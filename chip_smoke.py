@@ -17,9 +17,6 @@ engine's main path through the entry points a user calls:
   C  serving: a ``GraphServer`` answers 16 BFS point queries from 16 seeded
      roots as one fused batch; every depth vector must equal a numpy
      level-synchronous BFS exactly.
-  D  the fused Pallas sweep kernel: reported as skipped, because
-     ``execution="auto"`` never picks it (it does not lower for TPU; see
-     ``repro.kernels.packed_sweep.TPU_LOWERING_BLOCKER``).
 
 ``--chips 4`` runs only ``repro.core.distributed.distributed_pagerank`` on a
 2x2 mesh of ``jax.devices()`` and compares it with phase A's single-chip
@@ -326,8 +323,6 @@ def main(argv=None) -> int:
         else:
             phase_b(g, path, attrs_a, clock)
             phase_c(g, el, args.seed, clock)
-            log("phase D: skipped — execution='auto' never picks the fused "
-                "kernel: it does not lower for TPU")
     finally:
         shutil.rmtree(graph_dir, ignore_errors=True)
     log(f"total: wall_s={time.perf_counter() - t_start:.3f} "

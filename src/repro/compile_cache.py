@@ -1,9 +1,10 @@
 """JAX's persistent compilation cache, set up by the entry points.
 
-``enable()`` is called by ``chip_smoke.py``, ``examples/*.py`` and
-``benchmarks/run.py`` before they compile anything. It is deliberately not
-called on ``import repro``: the test suite compiles for described (not
-attached) TPUs, and such compiles must not land in a cache.
+``enable()`` is called by ``chip_smoke.py``, ``examples/*.py`` and the
+benchmark harness (``bench/harness.py``) before they compile anything. It
+is deliberately not called on ``import repro``: the test suite compiles
+for described (not attached) TPUs, and such compiles must not land in a
+cache.
 
 Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and this
 module sets nothing. Otherwise the cache lives at a fixed path inside the

@@ -155,31 +155,25 @@ class PackedSweep:
     each. This layout cuts that stream into uniform ``(num_tiles,
     tile_edges)`` windows so the executor can run the entire gather-reduce
     phase as a single ``jax.lax.scan`` (or stream tile chunks host→device)
-    — one XLA dispatch instead of one host round-trip per sub-shard. The
-    same schema is what the fused Pallas backend
-    (:mod:`repro.kernels.packed_sweep`, ``execution="packed_kernel"``)
-    grids over: one ``(tile_edges,)`` leaf slice per grid cell, DMA'd
-    HBM→VMEM by BlockSpec index maps.
+    — one XLA dispatch instead of one host round-trip per sub-shard.
 
     **Cut rule (mode="adaptive"):** tiles are cut *only at destination-run
     boundaries* — a run being one sub-shard's maximal span of edges
     sharing a destination, i.e. exactly one hub slot. Large sub-shards
     therefore split across tiles and small consecutive sub-shards coalesce
     into shared tiles, but a destination's per-sub-shard edge run is never
-    divided, so a run-partial reduce (the per-block executor's, and the
-    fused kernel's over ``run_local``) sees whole runs, with near-uniform
-    tile occupancy (``padding_ratio`` stays small on power-law graphs
-    instead of being bound by the largest sub-shard). ``tile_edges`` is
-    chosen per graph to minimise total padded slots (see
-    :func:`choose_tile_edges`).
+    divided, so a run-partial reduce (the per-block executor's) sees
+    whole runs, with near-uniform tile occupancy (``padding_ratio`` stays
+    small on power-law graphs instead of being bound by the largest
+    sub-shard). ``tile_edges`` is chosen per graph to minimise total
+    padded slots (see :func:`choose_tile_edges`).
 
     **mode="subshard"** reproduces the legacy one-tile-per-sub-shard
     packing (tiles never cross or split sub-shards, ``tile_edges`` = the
-    largest sub-shard bucket) in the same schema — kept for the padding
-    benchmarks and because it is the only packing whose per-run reduce is
-    also valid for ``src_sorted`` (GraphChi-like) layouts, where a
-    destination's edges are not contiguous and only whole-sub-shard
-    windows group them correctly.
+    largest sub-shard bucket) in the same schema — kept because it is the
+    only packing whose per-run reduce is also valid for ``src_sorted``
+    (GraphChi-like) layouts, where a destination's edges are not
+    contiguous and only whole-sub-shard windows group them correctly.
 
     **Execution schema** (per tile):
 
@@ -195,19 +189,18 @@ class PackedSweep:
       ``run_local`` is precisely the ToHub windowed-partial formulation of
       ``kernels/dsss_spmv.py``, which is why tiles are also valid Pallas
       kernel inputs (:func:`repro.kernels.ops.prepare_from_packed_tile`).
-      The scan does not read it; the fused kernel does.
+      The scan does not read it.
     * ``run_dst`` — per run-slot global destination id (``n_pad`` sentinel
-      past ``u``): the fused kernel's FromHub fold scatters the ≤
-      ``tile_edges`` run partials into the flat accumulator. The scan
-      does not read it.
+      past ``u``): where a FromHub fold of the ≤ ``tile_edges`` run
+      partials lands in the flat accumulator. The scan does not read it.
     * ``e_valid`` — real edges; trailing padding is masked to exact
       ⊕-identities.
 
     Fold order. The scan folds each destination's in-edges left to right
     in the tile stream's order: XLA serialises conflicting scatter
     updates in order on CPU and TPU (implementation-defined on GPU).
-    The per-block executor and the fused kernel fold run partials in
-    ascending source-interval order instead. Min and max are order-free,
+    The per-block executor folds run partials in ascending
+    source-interval order instead. Min and max are order-free,
     so every backend gives bit-identical results; a float ``sum`` is
     reassociated across backends and agrees to float32 rounding. One
     backend run twice over the same tiles, whatever the residency or

@@ -42,12 +42,11 @@ class NXGraphEngine:
         disk-backed shared ``session`` opened via
         :meth:`GraphSession.open`). See :class:`GraphSession`. ``None``
         defaults to "auto" (host streaming iff a budget is set).
-      execution: "per_block" | "packed" | "packed_kernel" | "auto" —
+      execution: "per_block" | "packed" | "auto" —
         host-scheduled dispatch-per-sub-shard vs. one compiled scan per
-        update sweep (chunk-streamed under host residency) vs. the fused
-        Pallas tile kernel. See :class:`GraphSession`. ``None`` defaults
-        to "auto" ("packed" wherever it applies); results
-        and model meters are identical.
+        update sweep (chunk-streamed under host residency). See
+        :class:`GraphSession`. ``None`` defaults to "auto" ("packed"
+        wherever it applies); model meters are identical.
       packing: "adaptive" | "subshard" | "auto" tile layout for packed
         execution (see :class:`GraphSession`). ``None`` defaults to
         "auto".

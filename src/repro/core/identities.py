@@ -33,7 +33,6 @@ __all__ = [
     "reduce_identity",
     "padding_identity",
     "padding_identity_value",
-    "segment_fill_value",
 ]
 
 # The programs' saturating "infinite depth / distance" for integer min/max
@@ -83,28 +82,4 @@ def padding_identity_value(reduce: str, dtype) -> float | int:
         return big
     if reduce == "max":
         return -big
-    raise ValueError(f"unknown reduce {reduce!r}")
-
-
-def segment_fill_value(reduce: str, dtype):
-    """The *empty-segment* fill of ``jax.ops.segment_{sum,min,max}``.
-
-    A third family, distinct from both above: ``segment_max`` fills empty
-    int segments with ``iinfo.min`` (-2³¹), whereas :func:`padding_identity`
-    is ``-iinfo.max`` (-2³¹+1) — off by one. The fused packed-sweep kernel
-    (``kernels/packed_sweep.py``) initializes its windowed run accumulator
-    with this value so untouched slots are *bitwise* what the reference
-    segment ops of ``_packed_sweep_impl`` leave behind.
-    """
-    dt = jnp.dtype(dtype)
-    if reduce == "sum":
-        return jnp.zeros((), dt)
-    if jnp.issubdtype(dt, jnp.floating):
-        lo, hi = -jnp.inf, jnp.inf
-    else:
-        lo, hi = jnp.iinfo(dt).min, jnp.iinfo(dt).max
-    if reduce == "min":
-        return jnp.array(hi, dt)
-    if reduce == "max":
-        return jnp.array(lo, dt)
     raise ValueError(f"unknown reduce {reduce!r}")

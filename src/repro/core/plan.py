@@ -86,19 +86,15 @@ class ExecutionPlan:
         the session has a memory budget). See
         :class:`repro.core.session.GraphSession` for the semantics.
       execution: per-plan override of the session's execution axis —
-        ``None`` (inherit), "per_block", "packed", "packed_kernel" or
-        "auto". "per_block" is the host-scheduled legacy path (one jit
-        dispatch per sub-shard); "packed" runs each update sweep as one
-        compiled scan over the destination-aligned tile layout — under
-        host residency the tile chunks are streamed with double-buffered
-        prefetch, so out-of-core runs stay packed; "packed_kernel" runs
-        the same sweep inside the fused Pallas kernel
-        (:mod:`repro.kernels.packed_sweep` — CPU interpret mode only; it
-        does not lower for TPU yet, and asking for it there raises). All
-        packed modes are SPU/DPU/MPU only; fused/custom schedules
-        downgrade to "per_block". "auto" picks "packed" wherever it
-        applies. Results and modelled meters
-        are identical in every case. See
+        ``None`` (inherit), "per_block", "packed" or "auto". "per_block"
+        is the host-scheduled legacy path (one jit dispatch per
+        sub-shard); "packed" runs each update sweep as one compiled scan
+        over the destination-aligned tile layout — under host residency
+        the tile chunks are streamed with double-buffered prefetch, so
+        out-of-core runs stay packed. "packed" is SPU/DPU/MPU
+        only; fused/custom schedules downgrade to "per_block". "auto"
+        picks "packed" wherever it applies. Modelled meters are identical
+        in every case, and results within the fold contract. See
         :class:`repro.core.session.GraphSession`.
       activity: frontier-aware selective execution — ``"auto"`` (default)
         lets monotone programs (BFS/SSSP/WCC — ``program.monotone``) skip
@@ -164,12 +160,10 @@ class ExecutionPlan:
                 "residency must be None, 'device', 'host', 'disk' or 'auto', "
                 f"got {self.residency!r}"
             )
-        if self.execution not in (
-            None, "per_block", "packed", "packed_kernel", "auto"
-        ):
+        if self.execution not in (None, "per_block", "packed", "auto"):
             raise ValueError(
-                "execution must be None, 'per_block', 'packed', "
-                f"'packed_kernel' or 'auto', got {self.execution!r}"
+                "execution must be None, 'per_block', 'packed' or 'auto', "
+                f"got {self.execution!r}"
             )
         if self.activity not in ("auto", "off"):
             raise ValueError(

@@ -141,12 +141,11 @@ __all__ = [
 
 
 # The *modelled* Meters fields — identical across execution modes and
-# residencies by contract (tests/test_packed_sweep.py, the residency
-# property suite and bench_sweep all compare exactly this set; keeping the
-# one list here is what stops the three from drifting when a field is
-# added). The remaining fields (wall_seconds, bytes_h2d,
-# peak_device_graph_bytes) are physical: they describe whichever data path
-# actually ran.
+# residencies by contract (tests/test_packed_sweep.py and the residency
+# property suite compare exactly this set; keeping the one list here is
+# what stops them from drifting when a field is added). The remaining
+# fields (wall_seconds, bytes_h2d, peak_device_graph_bytes) are physical:
+# they describe whichever data path actually ran.
 MODEL_METER_FIELDS = (
     "bytes_read_edges",
     "bytes_read_intervals",
@@ -378,10 +377,9 @@ class CompiledPlan:
     resident: frozenset
     residency: str = "device"
     host_cached: frozenset = frozenset()
-    # Resolved execution mode: "packed" (scan) or "packed_kernel" (fused
-    # Pallas kernel) iff a compiled sweep path will actually run (an
-    # SPU/DPU/MPU schedule — either residency), else "per_block".
-    # Never "auto".
+    # Resolved execution mode: "packed" (the scan) iff a compiled sweep
+    # path will actually run (an SPU/DPU/MPU schedule — any residency),
+    # else "per_block". Never "auto".
     execution: str = "per_block"
     # Resolved activity mode: "selective" iff the program is monotone and
     # the plan's activity axis is "auto" — frontier-aware interval/tile/
@@ -977,59 +975,6 @@ def _packed_select_jits(donate: bool):
     )
 
 
-@functools.lru_cache(maxsize=None)
-def _packed_kernel_jits(donate: bool):
-    """The fused Pallas sweep executable (``execution="packed_kernel"``).
-
-    Call-signature-identical to ``_packed_jits``'s sweep, so the
-    streaming (``_packed_host_sweep``) and slab (``_sweep_tile_slab``)
-    drivers run either executable unchanged. The kernel resolves its own
-    interpret flag at trace time (compiled on TPU, interpreted
-    elsewhere); the batched apply is shared with the scan path.
-    """
-    from repro.kernels.packed_sweep import packed_sweep_update
-
-    donate_kw = {"donate_argnums": (2,)} if donate else {}
-
-    def _sweep(
-        program, attrs_flat, acc_flat, aux, tiles, row_active,
-        has_weights, aux_batched=False,
-    ):
-        return packed_sweep_update(
-            program, attrs_flat, acc_flat, aux, tiles, row_active,
-            has_weights, aux_batched,
-        )
-
-    return jax.jit(
-        _sweep,
-        static_argnames=("program", "has_weights", "aux_batched"),
-        **donate_kw,
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def _packed_kernel_select_jits(donate: bool):
-    """The compacted-gather fused-kernel executable (selective path)."""
-    from repro.kernels.packed_sweep import packed_sweep_update_select
-
-    donate_kw = {"donate_argnums": (2,)} if donate else {}
-
-    def _select(
-        program, attrs_flat, acc_flat, aux, tiles, idx, a_valid,
-        row_active, has_weights, aux_batched=False,
-    ):
-        return packed_sweep_update_select(
-            program, attrs_flat, acc_flat, aux, tiles, idx, a_valid,
-            row_active, has_weights, aux_batched,
-        )
-
-    return jax.jit(
-        _select,
-        static_argnames=("program", "has_weights", "aux_batched"),
-        **donate_kw,
-    )
-
-
 # ---------------------------------------------------------------------------
 # Per-run context handed to the iteration bodies.
 # ---------------------------------------------------------------------------
@@ -1049,7 +994,6 @@ class _RunContext:
     fetcher: _BlockFetcher = None  # type: ignore[assignment]
     activity: str = "off"  # resolved activity ("selective" | "off")
     aux_batched: bool = False  # aux leaves carry a leading (K,) query axis
-    execution: str = "per_block"  # resolved execution (never "auto")
     trace: bool = False  # record the sweep's phase spans (TraceSpec.sweeps)
     tiles_swept: int = 0  # tiles the run's scans covered so far
 
@@ -1443,12 +1387,7 @@ def _sweep_tile_slab(
     bucket = min(next_bucket(int(local.size)), count)
     idx = np.zeros(bucket, np.int32)
     idx[: local.size] = local
-    select_jits = (
-        _packed_kernel_select_jits
-        if ctx.execution == "packed_kernel"
-        else _packed_select_jits
-    )
-    select = select_jits(jax.default_backend() != "cpu")
+    select = _packed_select_jits(jax.default_backend() != "cpu")
     _count_tiles(ctx, int(local.size))
     with (
         _TRACER.span("sweep.scan", tiles=int(local.size), bucket=bucket)
@@ -1466,12 +1405,10 @@ def _count_tiles(ctx: _RunContext, n: int) -> None:
     """Charge ``n`` tiles to the run and ``repro_engine_tiles_swept_total``
     at the scan's dispatch site, and to
     ``repro_engine_vertex_message_tiles_total`` where the scan gathers one
-    per-vertex message per edge (the fused kernel keeps its own gather)."""
+    per-vertex message per edge."""
     ctx.tiles_swept += n
     _OBS_TILES.inc(n)
-    if ctx.execution != "packed_kernel" and _vertex_message_applies(
-        ctx.program, ctx.session.has_weights
-    ):
+    if _vertex_message_applies(ctx.program, ctx.session.has_weights):
         _OBS_MSG_TILES.inc(n)
 
 
@@ -1642,10 +1579,6 @@ def _iteration_packed(ctx: _RunContext, attrs, active, meters: Meters):
             sess._packed_tile_activity(row_mask) if selective else None
         )
     sweep, apply_all = _packed_jits(jax.default_backend() != "cpu")
-    if ctx.execution == "packed_kernel":
-        # Same streaming/selective drivers, fused-kernel sweep executable
-        # (the batched apply is shared — it is already one dispatch).
-        sweep = _packed_kernel_jits(jax.default_backend() != "cpu")
     if ctx.residency in ("host", "disk"):
         acc = _packed_host_sweep(
             ctx, attrs_flat, acc, row_active, meters, sweep, tile_active
@@ -1825,10 +1758,9 @@ class _StagedGraph:
         ids, windowed run slots, the run→destination scatter map, weights
         when present, and the valid edge count); per-tile metadata
         (``base_slot``/``u``/``row_offset``/intervals) stays host-side on
-        the :class:`~repro.core.dsss.PackedSweep` for meter accounting,
-        stream planning and kernel-path consumers. Packed device mode
-        never stages the per-block device mirror — these arrays *are* the
-        device topology.
+        the :class:`~repro.core.dsss.PackedSweep` for meter accounting
+        and stream planning. Packed device mode never stages the per-block
+        device mirror — these arrays *are* the device topology.
         """
         tiles = self._packed_tiles.get(mode)
         if tiles is None:
@@ -2063,19 +1995,6 @@ class GraphSession:
           way (``bytes_h2d``/``peak_device_graph_bytes`` report the
           physical transfers of whichever path ran). Custom and fused
           schedules downgrade to ``"per_block"`` (they own their loop).
-        * ``"packed_kernel"`` — the fused-kernel path: the same staged
-          tile layout, but the sweep's gather→combine→run-reduce→
-          hub-scatter runs inside one Pallas kernel
-          (:func:`repro.kernels.packed_sweep.packed_sweep_update`) that
-          grids over the tile axis with BlockSpec-pipelined HBM→VMEM
-          tile DMA. Streaming, selective compaction, batching and every
-          meter work exactly as under ``"packed"`` — only the sweep
-          executable differs; results are bit-identical and model
-          meters field-identical by construction (and by the parity
-          suite). The kernel does not lower for TPU yet, so it runs
-          only on CPU, in interpret mode (validation only); requesting
-          it on a TPU backend raises. Downgrades like ``"packed"`` for
-          custom/fused schedules.
         * ``"auto"`` (default) — ``"packed"`` wherever packed applies,
           ``"per_block"`` where it does not.
 
@@ -2123,10 +2042,10 @@ class GraphSession:
                 "residency must be 'device', 'host', 'disk' or 'auto', "
                 f"got {residency!r}"
             )
-        if execution not in ("per_block", "packed", "packed_kernel", "auto"):
+        if execution not in ("per_block", "packed", "auto"):
             raise ValueError(
-                "execution must be 'per_block', 'packed', 'packed_kernel' "
-                f"or 'auto', got {execution!r}"
+                "execution must be 'per_block', 'packed' or 'auto', "
+                f"got {execution!r}"
             )
         if packing not in ("adaptive", "subshard", "auto"):
             raise ValueError(
@@ -2332,7 +2251,7 @@ class GraphSession:
         residency: str,
         override: str | None = None,
     ) -> str:
-        """Resolve the execution axis: 'per_block' | 'packed' | 'packed_kernel'.
+        """Resolve the execution axis: 'per_block' | 'packed'.
 
         ``strategy`` must already be resolved (a schedule name, not
         "auto") and ``residency`` must be 'device' or 'host'. The packed
@@ -2340,15 +2259,10 @@ class GraphSession:
         *both* residencies — under "host" the tile chunks are streamed
         with double-buffered prefetch instead of the per-block fetcher, so
         out-of-core runs no longer downgrade. ``"auto"`` resolves to the
-        XLA scan ``"packed"`` on every platform: the fused Pallas kernel
-        does not lower for TPU
-        (:data:`repro.kernels.packed_sweep.TPU_LOWERING_BLOCKER`), so an
-        explicit ``"packed_kernel"`` on a TPU backend raises, and on CPU
-        it runs in interpret mode (the parity suite requests it
-        explicitly). The fused fast path and custom registered schedules
-        run per-block even when a packed mode was requested explicitly (a
-        forgiving downgrade, like residency="auto": results and meters are
-        identical).
+        XLA scan ``"packed"`` on every platform. The fused fast path and
+        custom registered schedules run per-block even when ``"packed"``
+        was requested explicitly (a forgiving downgrade, like
+        residency="auto": results and meters are identical).
         """
         mode = override or self.execution
         applies = strategy in ("spu", "dpu", "mpu")
@@ -2356,14 +2270,6 @@ class GraphSession:
             return "per_block"
         if mode == "auto":
             return "packed"
-        if mode == "packed_kernel" and jax.default_backend() == "tpu":
-            from repro.kernels.packed_sweep import TPU_LOWERING_BLOCKER
-
-            raise NotImplementedError(
-                "execution='packed_kernel' cannot run on TPU: "
-                f"{TPU_LOWERING_BLOCKER}. Use execution='packed' (what "
-                "'auto' resolves to)."
-            )
         return mode
 
     # -- budget accounting ---------------------------------------------------
@@ -3104,10 +3010,9 @@ class GraphSession:
                 fetcher=fetcher,
                 activity=compiled.activity,
                 aux_batched=aux_batched,
-                execution=compiled.execution,
                 trace=trace_sweeps,
             )
-            if compiled.execution in ("packed", "packed_kernel"):
+            if compiled.execution == "packed":
                 iteration = _iteration_packed
             else:
                 iteration = self._strategies[compiled.choice.strategy]

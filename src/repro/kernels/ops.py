@@ -172,11 +172,8 @@ def prepare_from_packed_tile(packed, t: int, dtype, *, gather_op: str, reduce: s
 def prepare_packed_tiles(packed, *, has_weights: bool) -> dict:
     """Stage the full tile-packed sweep layout as device operand leaves.
 
-    The one upload both compiled backends share: the scan path
-    (``core/session.py::_packed_sweep_impl``) carries these leaves through
-    ``lax.scan``, and the fused kernel
-    (:func:`repro.kernels.packed_sweep.packed_sweep_update`) grids over
-    their leading (NT,) tile axis with BlockSpec-pipelined HBM→VMEM DMA.
+    The scan path (``core/session.py::_packed_sweep_impl``) carries these
+    leaves through ``lax.scan`` over their leading (NT,) tile axis.
     Per-tile metadata (``base_slot``/``u``/``row_offset``/intervals) stays
     host-side on the :class:`~repro.core.dsss.PackedSweep` for meter
     accounting and stream planning.

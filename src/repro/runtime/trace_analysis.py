@@ -4,10 +4,7 @@ Reads what :class:`repro.obs.Tracer` writes — Chrome ``trace_event``
 JSON (``TraceSpec(path="run.json")`` / ``Tracer.export``) or the raw
 one-span-per-line ``.jsonl`` dump — and folds the span stream back into
 per-run facts: how long each update sweep took, how many bytes each
-moved, which execution backend ran. ``benchmarks/roofline_report.py
---trace`` joins these summaries against the analytic per-sweep roofline
-(:func:`sweep_execution_model`) to report measured-vs-modelled time per
-backend.
+moved, which execution backend ran.
 
 The join key is the ``run`` id the engine stamps into every span it
 records for one ``_execute`` call — "sweep" and "checkpoint" spans carry
@@ -18,7 +15,7 @@ from __future__ import annotations
 
 import json
 
-__all__ = ["load_events", "run_summaries", "fmt_run_table"]
+__all__ = ["load_events", "run_summaries"]
 
 
 def load_events(path: str) -> list[dict]:
@@ -102,21 +99,3 @@ def run_summaries(events: list[dict]) -> list[dict]:
             }
         )
     return out
-
-
-def fmt_run_table(summaries: list[dict]) -> str:
-    """Markdown table of per-run sweep facts (the ``--trace`` report)."""
-    hdr = (
-        "| run | program | backend | residency | sweeps | mean sweep (ms) "
-        "| h2d MB | disk MB |"
-    )
-    lines = [hdr, "|" + "---|" * 8]
-    for r in summaries:
-        lines.append(
-            f"| {r['run']} | {r['program']} | {r['execution']} | "
-            f"{r['residency']} | {r['sweeps']} | "
-            f"{r['mean_sweep_s'] * 1e3:.2f} | "
-            f"{r['bytes_h2d'] / 1e6:.2f} | "
-            f"{r['bytes_disk_read'] / 1e6:.2f} |"
-        )
-    return "\n".join(lines)

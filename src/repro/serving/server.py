@@ -133,7 +133,7 @@ def estimate_inflight_parts(
     ba = plan.program.attr_bytes
     attr = 2.0 * g.n_pad * ba * k
     if compiled.residency in ("host", "disk"):
-        if compiled.execution in ("packed", "packed_kernel"):
+        if compiled.execution == "packed":
             splan = session.packed_stream_plan(compiled.choice.strategy, ba)
             topo = splan.pin_model_bytes + 2.0 * splan.max_chunk_model_bytes
         else:

@@ -2,8 +2,8 @@
 
 The ``packed`` scan scatters each edge's contribution straight into the
 accumulator at its destination, left to right in tile order. The
-``per_block`` executor and the ``packed_kernel`` backend first reduce a
-destination's edges into run partials, then fold the partials. Min and
+``per_block`` executor first reduces a destination's edges into run
+partials, then folds the partials. Min and
 max are order-free, so those programs agree bit for bit. A float32 sum
 is reassociated, so PageRank and PPR agree to float32 rounding over a
 destination's in-edges: within ``SUM_RTOL`` relative, plus ``SUM_ATOL``

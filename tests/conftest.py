@@ -21,7 +21,6 @@ PROPERTY_TEST_MODULES = [
     "test_iomodel_property.py",
     "test_kernels_dsss_spmv.py",
     "test_kernels_flash_attention.py",
-    "test_packed_kernel_property.py",
     "test_packed_tiling_property.py",
     "test_reliability_property.py",
     "test_residency_property.py",

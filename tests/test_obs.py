@@ -13,8 +13,8 @@ spans are emitted at the same lines that charge ``Meters``, so
 
 Plus the plumbing: Prometheus render/parse round-trip, registry gating
 (``REPRO_OBS=0`` semantics), tracer ring + Chrome export + the
-``python -m repro.obs export-trace`` CLI, iomodel drift gauges,
-checkpoint/storage counters, and the benchmark payload stamp.
+``python -m repro.obs export-trace`` CLI, iomodel drift gauges and
+checkpoint/storage counters.
 """
 import dataclasses
 import json
@@ -309,6 +309,36 @@ class TestEngineTracing:
         assert run_span["args"]["bytes_h2d"] == res.meters.bytes_h2d
         assert run_span["args"]["residency"] == "disk"
 
+    def test_trace_summary_bytes_equal_meters_on_disk(self, tmp_path):
+        """A disk-tier PageRank on an R-MAT graph (scale 9, P = 8) under a
+        quarter of its edge bytes: the run summary that
+        ``repro.runtime.trace_analysis`` reads back from the exported
+        trace carries exactly the run's ``bytes_h2d`` and
+        ``bytes_disk_read``."""
+        from repro.graph.generators import rmat
+        from repro.runtime.trace_analysis import load_events, run_summaries
+
+        el = degree_and_densify(*rmat(9, 16, seed=0), drop_self_loops=True)
+        g = build_dsss(el, 8)
+        store = str(tmp_path / "g.dsss")
+        write_dsss(g, store)
+        budget = int(g.total_edge_bytes(8) * 0.25)
+        sess = GraphSession.open(
+            store, memory_budget=budget, host_memory_budget=2 * budget
+        )
+        assert sess.resolved_residency() == "disk"
+        path = str(tmp_path / "run.json")
+        res = sess.run(
+            ExecutionPlan(
+                PageRank(), max_iters=5, tol=0.0, trace=TraceSpec(path=path)
+            )
+        )
+        (summary,) = run_summaries(load_events(path))
+        assert summary["sweeps"] == res.meters.iterations
+        assert summary["bytes_h2d"] == res.meters.bytes_h2d
+        assert summary["bytes_disk_read"] == res.meters.bytes_disk_read
+        assert res.meters.bytes_disk_read > 0
+
     def test_trace_records_staging_and_checkpoint(self, graph, tmp_path):
         # Fresh device session: the first fused run always stages, so a
         # cat="staging" span is guaranteed alongside the checkpoint ones.
@@ -446,7 +476,7 @@ class TestServingTelemetry:
 
 
 # ---------------------------------------------------------------------------
-# checkpoint + benchmark stamp
+# checkpoint counters
 # ---------------------------------------------------------------------------
 class TestCheckpointCounters:
     def test_save_snapshot_publishes_counters(self, graph, tmp_path):
@@ -462,19 +492,3 @@ class TestCheckpointCounters:
         sess.run(plan)
         assert REGISTRY.value("repro_checkpoint_saves_total") - before_saves == 2
         assert REGISTRY.value("repro_checkpoint_bytes_total") > before_bytes
-
-
-class TestBenchStamp:
-    def test_stamp_fields(self):
-        sys.path.insert(0, ".")
-        try:
-            from benchmarks._util import BENCH_SCHEMA_VERSION, stamp
-        finally:
-            sys.path.pop(0)
-        payload = stamp({"rows": []}, bench="t")
-        meta = payload["meta"]
-        assert meta["schema_version"] == BENCH_SCHEMA_VERSION
-        assert meta["bench"] == "t"
-        for key in ("git_sha", "backend", "created_utc", "created_unix",
-                    "python", "platform"):
-            assert meta[key], key

@@ -1,9 +1,9 @@
 """Tile-packed compiled sweeps vs. the per-block executor.
 
 The packed path's contract: for every native schedule (SPU/DPU/MPU),
-every program family (sum / min on weighted+unweighted graphs), both
-residencies (device-staged and host-streamed) and batched K > 1 runs, it
-must produce
+every program family (float sum, int min, weighted float min, max), every
+residency (device-staged, host-streamed, disk-streamed), both activity
+modes and batched K > 1 runs, it must produce
 
   * bit-identical attributes and outputs for min and max programs, and
     for float sums (PageRank) attributes within ``rtol=1e-5``,
@@ -49,18 +49,31 @@ from repro.core import session as session_mod
 from repro.core.vertex_programs import MaxLabelForward
 from repro.graph.generators import erdos_renyi, ring, zipf
 from repro.graph.preprocess import degree_and_densify
+from repro.storage import write_dsss
 
 STRATEGIES = ["spu", "dpu", "mpu"]
 RESIDENCIES = ["device", "host"]
 
-# (label, program factory, plan kwargs, weighted) — PageRank exercises the
-# float-sum semiring (where re-association would show), BFS the monotone
-# int-min path with activity skipping, SSSP the weighted float-min path.
-PROGRAMS = [
-    ("pagerank", PageRank, dict(max_iters=6, tol=0.0), True),
-    ("bfs", BFS, dict(max_iters=100, program_kwargs={"root": 0}), False),
-    ("sssp", SSSP, dict(max_iters=100, program_kwargs={"root": 0}), True),
-]
+# memory_budget chosen so MPU resolves to a strict 0 < Q < P split for
+# both attribute widths (Ba=4 min-programs and Ba=8 PageRank), so the
+# mixed direct+hub two-phase path really runs. Under "host" and "disk"
+# the same budget also forces real streaming (it is far below the graph
+# bytes); the disk tier's host cache holds only some of the tile chunks.
+BUDGET = 720
+HOST_BUDGET = 3000
+
+# label -> (graph kind, program factory, plan kwargs). PageRank exercises
+# the float-sum semiring (where re-association would show), BFS the
+# monotone int-min path with activity skipping, SSSP the weighted
+# float-min path, WCC int-min label propagation on a symmetric graph and
+# max-label the max semiring with per-vertex aux.
+PROGRAMS = {
+    "pagerank": ("weighted", PageRank, dict(max_iters=6, tol=0.0)),
+    "bfs": ("plain", BFS, dict(program_kwargs={"root": 0})),
+    "sssp": ("weighted", SSSP, dict(program_kwargs={"root": 0})),
+    "wcc": ("symmetric", WCC, {}),
+    "max-label": ("plain", MaxLabelForward, {}),
+}
 
 # Modelled meter fields — must be identical across execution modes AND
 # residencies. The remaining fields (bytes_h2d, peak_device_graph_bytes,
@@ -95,42 +108,79 @@ def _assert_equivalent(res_pb, res_pk, reduce, model_only=False):
     )
 
 
-def _session(g, residency):
-    # memory_budget chosen so MPU resolves to a strict 0 < Q < P split for
-    # both attribute widths (Ba=4 min-programs and Ba=8 PageRank), so the
-    # mixed direct+hub two-phase path really runs. Under "host" the same
-    # budget also forces real streaming (it is far below the graph bytes).
-    return GraphSession(g, memory_budget=720, residency=residency)
+@pytest.fixture(scope="module")
+def staged(tmp_path_factory):
+    """Each graph kind of :data:`PROGRAMS`, in memory and as a .dsss store."""
+    weighted = _graph(seed=3, weighted=True)
+    plain = _graph(seed=3)
+    symmetric = build_dsss(
+        degree_and_densify(
+            *erdos_renyi(150, 900, seed=3), drop_self_loops=True
+        ).symmetrized(),
+        5,
+    )
+    out = {}
+    for kind, g in (
+        ("weighted", weighted), ("plain", plain), ("symmetric", symmetric)
+    ):
+        path = str(tmp_path_factory.mktemp("stores") / f"{kind}.dsss")
+        write_dsss(g, path)
+        out[kind] = (g, path)
+    return out
 
 
-@pytest.mark.parametrize("label,prog_cls,kwargs,weighted", PROGRAMS)
+def _session(g, residency, path=None):
+    if residency == "disk":
+        return GraphSession.open(
+            path, memory_budget=BUDGET, host_memory_budget=HOST_BUDGET
+        )
+    return GraphSession(g, memory_budget=BUDGET, residency=residency)
+
+
+@pytest.mark.parametrize("label", list(PROGRAMS))
 @pytest.mark.parametrize("strategy", STRATEGIES)
-@pytest.mark.parametrize("residency", RESIDENCIES)
-def test_bit_identity_and_meters(
-    label, prog_cls, kwargs, weighted, strategy, residency
-):
-    g = _graph(seed=3, weighted=weighted)
-    sess = _session(g, residency)
+@pytest.mark.parametrize("residency", ["device", "host", "disk"])
+@pytest.mark.parametrize("activity", ["auto", "off"])
+def test_backend_parity(staged, label, strategy, residency, activity):
+    """``packed`` against ``per_block``: min/max results bit for bit, sums
+    within the fold contract, the same sweeps and convergence, identical
+    model meters everywhere and identical physical meters on device."""
+    kind, prog_cls, kwargs = PROGRAMS[label]
+    g, path = staged[kind]
+    sess = _session(g, residency, path)
+    assert sess.resolved_residency() == residency
+    if label == "max-label":
+        mask = np.random.default_rng(2).random(g.n) < 0.5
+        kwargs = dict(program_kwargs={"mask": mask})
+    kwargs = {"max_iters": g.n + 1, **kwargs}
     if strategy == "mpu":
         choice = sess.compile(ExecutionPlan(prog_cls(), strategy="mpu")).choice
         assert 0 < choice.Q < g.P, "budget must exercise the hub split"
-    pb = sess.run(
-        ExecutionPlan(prog_cls(), strategy=strategy, execution="per_block", **kwargs)
-    )
-    pk = sess.run(
-        ExecutionPlan(prog_cls(), strategy=strategy, execution="packed", **kwargs)
-    )
+
+    def run(execution):
+        return sess.run(
+            ExecutionPlan(
+                prog_cls(), strategy=strategy, execution=execution,
+                activity=activity, **kwargs,
+            )
+        )
+
+    pb, pk = run("per_block"), run("packed")
     # Model meters agree always; the physical fields additionally agree
     # under device residency (neither path streams: h2d 0, peak = total).
     _assert_equivalent(
-        pb, pk, prog_cls.reduce, model_only=(residency == "host")
+        pb, pk, prog_cls.reduce, model_only=(residency != "device")
     )
     assert pk.meters.edges_processed > 0
-    if residency == "host":
+    if residency != "device":
         assert pb.meters.bytes_h2d > 0 and pk.meters.bytes_h2d > 0
+    if residency == "disk":
+        assert pb.meters.bytes_disk_read > 0 and pk.meters.bytes_disk_read > 0
     if label == "pagerank":
         # Non-monotone: every sweep touches every sub-shard.
         assert pk.meters.blocks_processed == pk.iterations * len(sess.block_keys)
+    else:
+        assert pb.converged and pk.converged
 
 
 @pytest.mark.parametrize("residency", RESIDENCIES)
@@ -435,12 +485,14 @@ def test_kernel_operands_from_packed_tile():
 
 def test_invalid_execution_values_rejected():
     g = _graph(seed=1)
-    with pytest.raises(ValueError):
-        GraphSession(g, execution="warp")
+    accepted = r"'per_block', 'packed' or 'auto'"
+    for value in ("warp", "packed_kernel"):
+        with pytest.raises(ValueError, match=accepted):
+            GraphSession(g, execution=value)
+        with pytest.raises(ValueError, match=accepted):
+            ExecutionPlan(PageRank(), execution=value)
     with pytest.raises(ValueError):
         GraphSession(g, packing="diagonal")
-    with pytest.raises(ValueError):
-        ExecutionPlan(PageRank(), execution="warp")
 
 
 def _message_case(case, g, rng):
@@ -560,45 +612,3 @@ def test_scan_step_folds_with_one_scatter(prog_cls):
         static_argnames=("program", "has_weights"),
     ).lower(*args[:6], has_weights=False)
     assert lowered.as_text().count('"stablehlo.scatter"(') == 1
-
-
-@pytest.mark.parametrize("strategy", STRATEGIES)
-@pytest.mark.parametrize("label", ["bfs", "sssp", "wcc", "max-label"])
-def test_min_max_programs_bitwise_equal_per_block(label, strategy):
-    """Min and max are order-free: the one-scatter fold leaves BFS, SSSP,
-    WCC and max-label results bit for bit equal to the per-block
-    executor's under every schedule."""
-    src, dst = erdos_renyi(150, 900, seed=11)
-    w = np.random.default_rng(11).uniform(0.1, 2.0, len(src)).astype(np.float32)
-    el = degree_and_densify(
-        src, dst, weights=w if label == "sssp" else None, drop_self_loops=True
-    )
-    if label == "wcc":
-        el = el.symmetrized()
-    g = build_dsss(el, 5)
-    prog, kw = {
-        "bfs": (BFS(), {"root": 0}),
-        "sssp": (SSSP(), {"root": 0}),
-        "wcc": (WCC(), {}),
-        "max-label": (
-            MaxLabelForward(),
-            {"mask": np.random.default_rng(2).random(g.n) < 0.5},
-        ),
-    }[label]
-    sess = _session(g, "device")
-    if strategy == "mpu":
-        choice = sess.compile(ExecutionPlan(prog, strategy="mpu")).choice
-        assert 0 < choice.Q < g.P, "budget must exercise the hub split"
-
-    def run(execution):
-        return sess.run(
-            ExecutionPlan(
-                prog, strategy=strategy, execution=execution,
-                max_iters=g.n + 1, program_kwargs=kw,
-            )
-        )
-
-    pb, pk = run("per_block"), run("packed")
-    np.testing.assert_array_equal(pb.attrs, pk.attrs)
-    assert pb.iterations == pk.iterations
-    assert pb.converged and pk.converged

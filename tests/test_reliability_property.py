@@ -17,6 +17,7 @@ import os
 import tempfile
 
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.reliability import (
@@ -113,7 +114,7 @@ def test_keep_n_retention(sweeps, keep):
 @given(
     seed=st.integers(0, 2**31),
     rate=st.floats(min_value=0.0, max_value=1.0),
-    times=st.one_of(st.none(), st.integers(0, 8)),
+    times=st.one_of(st.none(), st.integers(1, 8)),
     n=st.integers(1, 64),
 )
 def test_injector_is_deterministic_and_budgeted(seed, rate, times, n):
@@ -153,3 +154,10 @@ def test_storage_decisions_deterministic(seed, n):
     decisions = run()
     for attempt, d in enumerate(decisions):
         assert d == ("corrupt" if attempt < 3 else None)
+
+
+def test_zero_times_budget_rejected():
+    """``times`` counts faults to fire: 0 would be a spec that never
+    fires, so it is refused (``None`` means unlimited)."""
+    with pytest.raises(ValueError, match="times"):
+        FaultSpec(site="h2d", kind="transient", rate=1.0, times=0)

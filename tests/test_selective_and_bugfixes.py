@@ -33,7 +33,7 @@ from repro.core import (
     wcc,
 )
 from repro.core.vertex_programs import MaxLabelForward
-from repro.graph.generators import erdos_renyi, ring
+from repro.graph.generators import erdos_renyi, ring, rmat
 from repro.graph.preprocess import degree_and_densify
 
 
@@ -193,6 +193,63 @@ class TestSelectiveExecution:
         assert any(log.sum() == 1 for log in on.activity_log)
         # ...and activity="off" records full sweeps.
         assert all(log.all() for log in off.activity_log)
+
+    def test_late_iteration_skip_ratio_on_rmat(self):
+        """BFS on an R-MAT graph streamed under a tight budget: each
+        sweep's physical ``bytes_h2d`` is the closed form over its logged
+        frontier, and the trailing sweeps, whose frontier has collapsed
+        to at most P/2 intervals, stream at least 5x fewer bytes than full
+        sweeps would."""
+        from repro.core.iomodel import (
+            packed_h2d_bytes,
+            selective_streamed_tiles,
+        )
+
+        P = 16
+        el = degree_and_densify(*rmat(13, 4, seed=0), drop_self_loops=True)
+        g = build_dsss(el, P)
+        # Nothing pins and chunks are fine-grained: the regime where
+        # skipping inactive streamed chunks can bite.
+        budget = int((2 * g.n_pad * 8 + g.total_edge_bytes(8)) * 0.05)
+        runs = {}
+        for activity in ("auto", "off"):
+            sess = GraphSession(g, memory_budget=budget, residency="host")
+            runs[activity] = sess, sess.run(
+                ExecutionPlan(
+                    BFS(), strategy="spu", max_iters=g.n + 1,
+                    execution="packed", activity=activity,
+                    program_kwargs={"root": 0},
+                )
+            )
+        sess, on = runs["auto"]
+        off = runs["off"][1]
+        np.testing.assert_array_equal(on.attrs, off.attrs)
+        assert on.iterations == off.iterations
+        splan = sess.packed_stream_plan("spu", BFS().attr_bytes)
+        full_sweep = packed_h2d_bytes(
+            splan.num_tiles - splan.pin_tiles, splan.tile_edges
+        )
+        per_sweep = [
+            packed_h2d_bytes(
+                selective_streamed_tiles(
+                    sess._packed_tile_activity(log),
+                    splan.pin_tiles,
+                    splan.chunk_tiles,
+                ),
+                splan.tile_edges,
+            )
+            for log in on.activity_log
+        ]
+        assert sum(per_sweep) == on.meters.bytes_h2d
+        assert off.meters.bytes_h2d == full_sweep * off.iterations
+        frontier = [int(log.sum()) for log in on.activity_log]
+        k = len(frontier)
+        while k > 0 and frontier[k - 1] <= P // 2:
+            k -= 1
+        late = range(k, len(frontier))
+        assert len(late) > 0, frontier
+        late_bytes = sum(per_sweep[i] for i in late)
+        assert full_sweep * len(late) >= 5 * late_bytes, (frontier, per_sweep)
 
     def test_non_monotone_programs_ignore_activity(self):
         g = _graph(seed=4)

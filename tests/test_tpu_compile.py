@@ -7,12 +7,10 @@ The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under several pytest workers
 only the worker given this file may.
 
-* The fused Pallas sweep kernel, sum form (PageRank, with aux) and min
-  form (BFS), for K ∈ {1, 16} at a VMEM-sized n_pad: the compiler refuses
-  it (``TPU_LOWERING_BLOCKER``), recorded as a strict xfail.
 * The XLA scan sweep, its selective variant and the batched apply at the
   ``chip_smoke.py`` size (n ≈ 4.85 M, ~67 M edge slots): each fits HBM.
-* The kernel's VMEM footprint rule against the described chip's kind.
+* ``execution="auto"`` resolves to the scan, and only the CPU backend
+  interprets Pallas kernels.
 """
 import os
 
@@ -24,13 +22,6 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import BFS, PageRank
 from repro.core import session as session_mod
 from repro.kernels.dsss_spmv import default_interpret
-from repro.kernels.packed_sweep import (
-    TPU_LOWERING_BLOCKER,
-    VMEM_BYTES,
-    kernel_fits_vmem,
-    packed_sweep_update,
-    resident_vmem_bytes,
-)
 
 HBM_BYTES = 16 * 10**9  # one TPU v5e chip (Google Cloud "TPU v5e" docs)
 
@@ -100,34 +91,6 @@ def _fits_hbm(compiled):
     return total
 
 
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError, reason=TPU_LOWERING_BLOCKER
-)
-@pytest.mark.parametrize("K", [1, 16])
-@pytest.mark.parametrize("program", ["pagerank", "bfs"])
-def test_fused_kernel_lowers(topo, one_chip, program, K):
-    n_pad, T, NT = 65_536, 4096, 16
-    prog, dtype, aux = _case(program, one_chip, n_pad)
-    n_vertex_aux = 2 if program == "pagerank" else 0
-    assert kernel_fits_vmem(
-        topo.devices[0].device_kind, n_pad, T, n_vertex_aux, len(aux) - n_vertex_aux
-    )
-
-    def sweep(attrs, acc, aux, tiles, row_active):
-        return packed_sweep_update(
-            prog, attrs, acc, aux, tiles, row_active, has_weights=False,
-            interpret=False,
-        )
-
-    jax.jit(sweep).lower(
-        _sds((K, n_pad), dtype, one_chip),
-        _sds((K, n_pad), dtype, one_chip),
-        aux,
-        _tiles(NT, T, one_chip),
-        _sds((SMOKE_P,), jnp.bool_, one_chip),
-    ).compile()
-
-
 @pytest.mark.parametrize(
     "program,K", [("pagerank", 1), ("bfs", 1), ("bfs", 16)]
 )
@@ -169,28 +132,8 @@ def test_scan_sweep_and_apply_fit_hbm_at_smoke_size(one_chip, program, K):
     )
 
 
-def test_kernel_vmem_footprint_rule(topo):
-    """The fit is judged from observable sizes against a per-kind VMEM
-    table; a device kind the table lacks is an error, not a default."""
-    kind = topo.devices[0].device_kind
-    assert VMEM_BYTES[kind] == 128 * 2**20
-    # Each (1, L) block pads to 8 sublanes; everything is double-buffered.
-    assert resident_vmem_bytes(1024, 128, 0) == 2 * 4 * 8 * (
-        4 * 1024 + 4 * 128 + 128
-    )
-    assert resident_vmem_bytes(1024, 128, 2, 1, True) == 2 * 4 * 8 * (
-        6 * 1024 + 5 * 128 + 2 * 128
-    )
-    assert kernel_fits_vmem(kind, 65_536, 4096, 2, 1)  # the compile test's size
-    assert kernel_fits_vmem(kind, 262_144, 4096, 0)  # BFS: 64 MiB of blocks
-    assert not kernel_fits_vmem(kind, 524_288, 4096, 0)
-    assert not kernel_fits_vmem(kind, SMOKE_N_PAD, SMOKE_T, 2, 1)
-    with pytest.raises(ValueError, match="'TPU v99'"):
-        kernel_fits_vmem("TPU v99", 1024, 128, 0)
-
-
 def test_auto_rule_and_interpret_resolution(topo, monkeypatch):
-    """auto never picks the refused kernel; only CPU interprets Pallas."""
+    """auto resolves to the scan on the TPU; only CPU interprets Pallas."""
     from repro.core import ExecutionPlan, GraphSession, build_dsss
     from repro.graph.generators import erdos_renyi
     from repro.graph.preprocess import degree_and_densify
@@ -205,8 +148,6 @@ def test_auto_rule_and_interpret_resolution(topo, monkeypatch):
     for residency in ("device", "host"):
         assert sess.resolved_execution("spu", residency) == "packed"
     assert sess.compile(ExecutionPlan(PageRank())).execution == "packed"
-    with pytest.raises(NotImplementedError, match="Only 2D gather"):
-        sess.resolved_execution("dpu", "device", "packed_kernel")
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="'gpu'"):
         default_interpret()
