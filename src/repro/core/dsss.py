@@ -19,6 +19,7 @@ byte model ``m·(Ba+Bv)/d`` falls out of these counts exactly.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -36,6 +37,7 @@ __all__ = [
     "tile_candidates",
     "tile_source_spans",
     "active_tile_mask",
+    "packed_run_ends",
 ]
 
 
@@ -180,31 +182,40 @@ class PackedSweep:
     * ``src`` / ``dst`` — global endpoint ids (vertex id == padded
       position, since intervals are the contiguous ranges
       ``[i·interval_size, …)``): the scan gathers attributes and aux
-      directly from the flat ``(n_pad,)`` arrays and scatters each edge's
-      contribution into the flat accumulator at ``dst`` (padding past
-      ``e_valid`` redirected to the ``n_pad`` drop sentinel), so a tile
-      needs no single source/destination interval and coalescing is free.
+      directly from the flat ``(n_pad,)`` arrays, and the per-edge fold
+      scatters each edge's contribution into the flat accumulator at
+      ``dst`` (padding past ``e_valid`` redirected to the ``n_pad`` drop
+      sentinel), so a tile needs no single source/destination interval
+      and coalescing is free.
     * ``run_local`` — per-edge hub slot *within the tile's slot window*
       (global hub slot − ``base_slot``): the per-tile segment reduce over
       ``run_local`` is precisely the ToHub windowed-partial formulation of
       ``kernels/dsss_spmv.py``, which is why tiles are also valid Pallas
       kernel inputs (:func:`repro.kernels.ops.prepare_from_packed_tile`).
-      The scan does not read it.
+      The run fold reads it for its segment starts.
     * ``run_dst`` — per run-slot global destination id (``n_pad`` sentinel
-      past ``u``): where a FromHub fold of the ≤ ``tile_edges`` run
-      partials lands in the flat accumulator. The scan does not read it.
+      past ``u``): where the run fold scatters each run's partial in the
+      flat accumulator.
+    * ``run_end`` — derived, not stored: per run slot, the tile position
+      of the run's last edge (0 past ``u``), ``(NT, R)`` with ``R`` the
+      smallest multiple of 128 at least ``max(u)``; ``None`` where the
+      run fold does not apply (:func:`packed_run_ends`).
     * ``e_valid`` — real edges; trailing padding is masked to exact
       ⊕-identities.
 
-    Fold order. The scan folds each destination's in-edges left to right
-    in the tile stream's order: XLA serialises conflicting scatter
-    updates in order on CPU and TPU (implementation-defined on GPU).
+    Fold order. Where ``run_end`` exists the scan's run fold reduces each
+    run with a segmented scan (a tree within the run), then scatters one
+    partial per run, left to right across runs and tiles in stream order;
+    otherwise it scatters each edge straight to ``dst``, left to right in
+    stream order. XLA serialises conflicting scatter updates in order on
+    CPU and TPU (implementation-defined on GPU).
     The per-block executor folds run partials in ascending
     source-interval order instead. Min and max are order-free,
-    so every backend gives bit-identical results; a float ``sum`` is
-    reassociated across backends and agrees to float32 rounding. One
-    backend run twice over the same tiles, whatever the residency or
-    chunking, folds in the same order and is bit-identical.
+    so every backend and both folds give bit-identical results; a float
+    ``sum`` is reassociated across backends and agrees to float32
+    rounding. One backend run twice over the same tiles, whatever the
+    residency or chunking, folds in the same order and is bit-identical:
+    a tile's fold depends only on the tile.
 
     ``src_interval`` / ``dst_interval`` / ``base_slot`` / ``row_offset`` /
     ``u`` are the per-tile metadata (intervals of the first edge, global
@@ -242,6 +253,68 @@ class PackedSweep:
     def padding_ratio(self) -> float:
         """Padded-slots / real-edges — 1.0 is a perfect packing."""
         return self.padded_edge_slots / max(self.m, 1)
+
+    @functools.cached_property
+    def run_end(self) -> np.ndarray | None:
+        """(NT, R) int32 last-edge position per run slot, derived once
+        (:func:`packed_run_ends`); ``None`` keeps the per-edge fold."""
+        return packed_run_ends(self.run_local, self.e_valid, self.u)
+
+    @property
+    def run_slots(self) -> int:
+        """``R``, the run fold's static run-slot width (0 without it)."""
+        return 0 if self.run_end is None else int(self.run_end.shape[1])
+
+
+# Run slots are padded to whole TPU lane rows.
+RUN_SLOT_ALIGN = 128
+# Tiles per block of the host derivation (bounds its temporaries).
+_RUN_END_BLOCK = 128
+
+
+def packed_run_ends(
+    run_local: np.ndarray, e_valid: np.ndarray, u: np.ndarray
+) -> np.ndarray | None:
+    """Each run's last edge position per tile, for the scan's run fold.
+
+    Returns an int32 ``(NT, R)`` array: ``run_end[t, r]`` is the last slot
+    ``k < e_valid[t]`` with ``run_local[t, k] == r``, and 0 for ``r`` past
+    the tile's ``u`` runs. ``R`` is the smallest multiple of
+    :data:`RUN_SLOT_ALIGN` at least ``max(u)``, static per packing.
+
+    Returns ``None`` — the scan then keeps the per-edge fold — unless
+    both hold:
+
+    * ``run_local`` is non-decreasing over every tile's real slots, so a
+      run's edges are contiguous (DSSS packings; a ``src_sorted``
+      layout's sub-shard tiles are not);
+    * ``2·R ≤ T``. The run fold issues one gather and one scatter per run
+      slot, ``R·(7.1 + 8.8)`` ns of indexed work per tile on one TPU v5e
+      (Graph500 scale 21: ~7.1 ns a gather index, ~8.8 ns a scatter
+      index), against the per-edge fold's ``T·8.8`` ns: it breaks even
+      near ``R = 0.55·T``, and ``2·R ≤ T`` leaves the rest for its dense
+      segmented scan. Graphs whose runs are mostly one edge long fail it.
+    """
+    nt, T = run_local.shape
+    if nt == 0:
+        return None
+    R = -(-int(np.max(u)) // RUN_SLOT_ALIGN) * RUN_SLOT_ALIGN
+    if R == 0 or 2 * R > T:
+        return None
+    run_end = np.zeros((nt, R), np.int32)
+    pos = np.arange(T, dtype=np.int32)
+    for lo in range(0, nt, _RUN_END_BLOCK):
+        rl = np.asarray(run_local[lo : lo + _RUN_END_BLOCK])
+        ev = np.asarray(e_valid[lo : lo + _RUN_END_BLOCK])[:, None]
+        next_live = pos[1:] < ev
+        if (next_live & (rl[:, 1:] < rl[:, :-1])).any():
+            return None
+        # A real slot ends its run unless the next slot is real and in it.
+        end = pos < ev
+        end[:, :-1] &= ~(next_live & (rl[:, 1:] == rl[:, :-1]))
+        t, k = np.nonzero(end)
+        run_end[lo + t, rl[t, k]] = k
+    return run_end
 
 
 def tile_source_spans(

@@ -218,18 +218,21 @@ def compare_measured(
 
 # Raw bytes per tile edge slot the packed host-streaming path ships: four
 # int32 leaves (src, dst, run_local, run_dst) — plus float32 weights on
-# weighted graphs and one int32 e_valid scalar per tile.
+# weighted graphs, one int32 e_valid scalar per tile and, where the run
+# fold applies, one int32 run_end entry per run slot.
 PACKED_SLOT_BYTES = 16
 
 
 def packed_h2d_bytes(
-    streamed_tiles: int, tile_edges: int, *, weighted: bool = False
+    streamed_tiles: int, tile_edges: int, *, weighted: bool = False,
+    run_slots: int = 0,
 ) -> float:
     """Closed-form raw host→device bytes per sweep for packed streaming.
 
     Packed host execution ships every non-pinned tile each sweep — dense
     index/run leaves, so the volume is a pure function of the layout:
-    ``streamed_tiles · (tile_edges · slot_bytes + 4)``. This is the packed
+    ``streamed_tiles · (tile_edges · slot_bytes + 4 · run_slots + 4)``,
+    with ``run_slots`` the packing's ``R`` (0 without the run fold). This is the packed
     counterpart of the per-block path's bucket-padded block bytes and is
     asserted to match ``Meters.bytes_h2d`` exactly in
     tests/test_packed_sweep.py — padding inflation (the adaptive packer's
@@ -237,7 +240,10 @@ def packed_h2d_bytes(
     is why bounding it matters out-of-core (GraphMP-style semi-external
     streaming pays for every padded slot on the wire).
     """
-    per_tile = tile_edges * (PACKED_SLOT_BYTES + (4 if weighted else 0)) + 4
+    per_tile = (
+        tile_edges * (PACKED_SLOT_BYTES + (4 if weighted else 0))
+        + 4 * run_slots + 4
+    )
     return float(streamed_tiles * per_tile)
 
 
@@ -249,8 +255,9 @@ def packed_disk_bytes(
     Under ``residency="disk"`` the packed executor ships the same dense
     tile leaves as the host path, but sourced from the mmap'd ``.dsss``
     tile section, so the per-sweep disk volume is the same pure function
-    of the layout as :func:`packed_h2d_bytes` — over only the tiles that
-    are neither device-pinned nor RAM-cached
+    of the layout as :func:`packed_h2d_bytes` — less the run fold's
+    ``run_end``, which is derived in RAM and not stored — over only the
+    tiles that are neither device-pinned nor RAM-cached
     (``num_tiles − pin_tiles − host_tiles`` of the session's
     :class:`~repro.core.session.PackedStreamPlan`). Asserted to match
     ``Meters.bytes_disk_read`` exactly in tests and the storage

@@ -193,6 +193,11 @@ _OBS_MSG_TILES = _REGISTRY.counter(
     "Tiles swept with one precomputed per-vertex message per edge "
     "(unweighted programs without destination aux, XLA scan sweep)",
 )
+_OBS_RUN_FOLD_TILES = _REGISTRY.counter(
+    "repro_engine_run_fold_tiles_total",
+    "Tiles swept by the run fold: a segmented scan over destination runs, "
+    "then one gather and one scatter per run (XLA scan sweep)",
+)
 _OBS_STREAM_CHUNKS = _REGISTRY.counter(
     "repro_engine_stream_chunks_total",
     "Streamed tile chunks dispatched under host/disk residency (the "
@@ -414,6 +419,7 @@ class PackedStreamPlan:
     pin_model_bytes: float  # real-edge model bytes of the pinned prefix
     max_chunk_model_bytes: float  # largest streamed chunk, model units
     host_tiles: int = 0
+    run_slots: int = 0  # R: the run fold's run_end width (0: per-edge fold)
 
 
 # ---------------------------------------------------------------------------
@@ -760,29 +766,95 @@ def _edge_contributions(
     )
 
 
-def _fold_tile(program, contrib, tile, acc_flat):
-    """Scatter-fold a tile's (K, T) contributions into the (K, n_pad)
-    accumulator at each edge's global ``dst``: one indexed pass per edge.
+_COMBINE = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
+_REDUCE = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}
+_LANES = 128  # one TPU lane row
 
-    Padding slots (past ``e_valid``) index the ``n_pad`` sentinel and are
-    dropped, so they leave the accumulator bit-untouched. Updates apply in
-    slot order (XLA serialises conflicting scatter updates in order on
-    CPU and TPU), so a destination's float sum is a left fold of its
-    in-edges in the tile stream's order: a reassociation of the per-block
-    executor's run-partial fold, equal to it for min and max.
+
+def _segmented_scan(reduce: str, vals, run_local):
+    """Inclusive ⊕-scan of ``vals`` (T,) within runs of equal
+    ``run_local`` (non-decreasing over the real slots): dense work, no
+    indexed access, T a multiple of 128.
+
+    The tile is laid out as (T/128, 128) lane rows. Seven Hillis–Steele
+    steps scan each row, folding in the value ``d`` lanes back where it
+    lies in the same run. A row's carry is then the ⊕ of the earlier
+    rows' last partials whose run continues into its first lane, one
+    masked reduction over row pairs; it is folded into the lanes of the
+    row's first run. Compiled for a v5e, the whole scan step holds ~25
+    fused ops this way and ~220 ops with ``jax.lax.associative_scan``,
+    whose log-depth levels each lower to their own slices and pads. Min
+    and max are exact; a sum is a tree within each run.
+    """
+    combine = _COMBINE[reduce]
+    ident = reduce_identity(reduce, vals.dtype)
+    v = vals.reshape(-1, _LANES)
+    seg = run_local.reshape(-1, _LANES)
+    lane = jnp.arange(_LANES)
+    d = 1
+    while d < _LANES:
+        back_v = jnp.pad(v[:, :-d], ((0, 0), (d, 0)), constant_values=ident)
+        back_seg = jnp.pad(seg[:, :-d], ((0, 0), (d, 0)))
+        same = (lane >= d) & (back_seg == seg)
+        v = jnp.where(same, combine(back_v, v), v)
+        d *= 2
+    rows = jnp.arange(v.shape[0])
+    feeds = (rows[:, None] < rows[None, :]) & (
+        seg[:, -1:] == seg[:, 0][None, :]
+    )
+    carry = _REDUCE[reduce](jnp.where(feeds, v[:, -1:], ident), axis=0)
+    first_run = seg == seg[:, :1]
+    v = jnp.where(first_run, combine(carry[:, None], v), v)
+    return v.reshape(vals.shape)
+
+
+def _fold_tile(program, contrib, tile, acc_flat):
+    """Fold a tile's (K, T) contributions into the (K, n_pad) accumulator.
+
+    Two folds, picked by the tile's structure (the ``run_end`` leaf is
+    staged only where :func:`repro.core.dsss.packed_run_ends` admits it):
+
+    * **Run fold** (``run_end`` present): a dense segmented scan over the
+      tile's destination runs (segments start where ``run_local``
+      changes), one gather of each run's total at its last edge
+      ``run_end``, and one scatter of the R partials at ``run_dst`` — 2·R
+      indexed operations in place of T. A float sum becomes a tree within
+      each run, then a left fold across runs and tiles in stream order.
+    * **Per-edge fold** (``run_end`` absent): one scatter per edge
+      straight to its global ``dst``, left to right in slot order.
+
+    Padding slots (past ``e_valid``) hold the ⊕-identity and index the
+    ``n_pad`` sentinel (per edge) or end no run; runs of a tile with no
+    real edge (a selective bucket's padding) are sent to the sentinel too,
+    so padding leaves the accumulator bit-untouched. XLA serialises
+    conflicting scatter updates in order on CPU and TPU. Min and max give
+    the same bits under either fold and equal the per-block executor's;
+    a float sum is a reassociation of the per-block run-partial fold.
     """
     n_pad = acc_flat.shape[-1]
-    dst = tile["dst"]
-    live = jnp.arange(dst.shape[-1]) < tile["e_valid"]
-    dst = jnp.where(live, dst, n_pad)
+    reduce = program.reduce
+    e_valid = tile["e_valid"]
+    by_runs = "run_end" in tile
+    if by_runs:
+        run_local, run_end = tile["run_local"], tile["run_end"]
+        dst = tile["run_dst"][: run_end.shape[-1]]
+        dst = jnp.where(run_end < e_valid, dst, n_pad)
+    else:
+        dst = tile["dst"]
+        dst = jnp.where(jnp.arange(dst.shape[-1]) < e_valid, dst, n_pad)
 
     def one(c, aq):
+        c = c.astype(aq.dtype)
+        if by_runs:
+            with jax.named_scope("run_scan"):
+                c = _segmented_scan(reduce, c, run_local)
+            with jax.named_scope("run_totals"):
+                c = c[run_end]
         with jax.named_scope("scatter_fold"):
             fold = aq.at[dst]
-            c = c.astype(aq.dtype)
-            if program.reduce == "sum":
+            if reduce == "sum":
                 return fold.add(c, mode="drop")
-            if program.reduce == "min":
+            if reduce == "min":
                 return fold.min(c, mode="drop")
             return fold.max(c, mode="drop")
 
@@ -802,22 +874,24 @@ def _packed_sweep_impl(
     """The gather-reduce phase of one update sweep over a tile sequence.
 
     Each scan step processes one destination-aligned tile: build the
-    tile's contributions from its global ``src`` ids, then scatter-fold
-    them straight into the flat accumulator at their global ``dst``
-    (:func:`_fold_tile`), one indexed pass per edge. The scan reads
-    neither ``run_local`` nor ``run_dst``. Min and max results equal the
-    per-block executor's bit for bit; a float sum folds each
-    destination's in-edges left to right in the tile stream's order, a
+    tile's contributions from its global ``src`` ids, then fold them
+    into the flat accumulator (:func:`_fold_tile`). Packings whose tiles
+    carry ``run_end`` take the run fold: a segmented scan over the tile's
+    destination runs, then one gather and one scatter per run (R wide).
+    The others take the per-edge fold: one scatter per edge at its global
+    ``dst`` (T wide). The choice is made at staging and read here from
+    the structure of ``tiles``. Min and max results equal the per-block
+    executor's bit for bit under either fold; a float sum is a
     reassociation of the per-block run-partial fold (equal up to float32
-    rounding, not bitwise). Every run of this executable folds in the
-    same order, so residency tiers, chunking, selective scans and resume
-    stay bit-identical to one another.
+    rounding, not bitwise). A tile's fold depends only on the tile, so
+    residency tiers, chunking, selective scans and resume stay
+    bit-identical to one another.
 
     Edges whose source interval is inactive this sweep (monotone activity
     tracking — the (P,) row mask is expanded to a per-vertex mask
     in-trace, so only P bools cross the host→device boundary per sweep)
     contribute exact ⊕-identities; edges past ``e_valid`` (tile padding)
-    index the ``n_pad`` sentinel and are dropped by the scatter.
+    leave the accumulator untouched under either fold.
     Unweighted programs without destination aux
     (:func:`_vertex_message_applies`) apply the activity mask once per
     vertex, in the messages built before the scan, and each step gathers
@@ -1349,6 +1423,8 @@ def _packed_host_chunk(packed, lo: int, hi: int, has_weights: bool) -> dict:
     }
     if has_weights:
         chunk["weights"] = packed.weights[lo:hi]
+    if packed.run_end is not None:
+        chunk["run_end"] = packed.run_end[lo:hi]
     return chunk
 
 
@@ -1403,13 +1479,17 @@ def _sweep_tile_slab(
 
 def _count_tiles(ctx: _RunContext, n: int) -> None:
     """Charge ``n`` tiles to the run and ``repro_engine_tiles_swept_total``
-    at the scan's dispatch site, and to
+    at the scan's dispatch site, to
     ``repro_engine_vertex_message_tiles_total`` where the scan gathers one
-    per-vertex message per edge."""
+    per-vertex message per edge, and to
+    ``repro_engine_run_fold_tiles_total`` where it folds by runs."""
+    sess = ctx.session
     ctx.tiles_swept += n
     _OBS_TILES.inc(n)
-    if _vertex_message_applies(ctx.program, ctx.session.has_weights):
+    if _vertex_message_applies(ctx.program, sess.has_weights):
         _OBS_MSG_TILES.inc(n)
+    if sess._staged.packed_host(sess.packing).run_end is not None:
+        _OBS_RUN_FOLD_TILES.inc(n)
 
 
 def _packed_host_sweep(
@@ -1521,6 +1601,8 @@ def _packed_host_sweep(
             meters.bytes_h2d += nb
             _OBS_H2D.inc(nb)
             if disk and not cached:
+                # run_end is derived in RAM, not read from the store.
+                nb -= host["run_end"].nbytes if "run_end" in host else 0
                 meters.bytes_disk_read += nb
                 _OBS_DISK.inc(nb)
             live = pin_model + model + (nxt[2] if nxt is not None else 0.0)
@@ -2377,10 +2459,11 @@ class GraphSession:
                 host_tiles = nt - pin
             else:
                 per_edge = PACKED_SLOT_BYTES + (4 if self.has_weights else 0)
+                per_tile = T * per_edge + 4 * packed.run_slots + 4
                 leftover = self.host_memory_budget
                 for lo in range(pin, nt, chunk):
                     hi = min(lo + chunk, nt)
-                    raw = (hi - lo) * (T * per_edge + 4)
+                    raw = (hi - lo) * per_tile
                     if leftover < raw:
                         break
                     leftover -= raw
@@ -2393,6 +2476,7 @@ class GraphSession:
             pin_model_bytes=pin_model,
             max_chunk_model_bytes=max_chunk,
             host_tiles=host_tiles,
+            run_slots=packed.run_slots,
         )
         self._stream_plans[key] = plan
         return plan

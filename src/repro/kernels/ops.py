@@ -176,7 +176,8 @@ def prepare_packed_tiles(packed, *, has_weights: bool) -> dict:
     leaves through ``lax.scan`` over their leading (NT,) tile axis.
     Per-tile metadata (``base_slot``/``u``/``row_offset``/intervals) stays
     host-side on the :class:`~repro.core.dsss.PackedSweep` for meter
-    accounting and stream planning.
+    accounting and stream planning. ``run_end`` is staged only where the
+    packing admits the run fold, which the scan reads from its presence.
     """
     tiles = {
         "src": jnp.asarray(packed.src),
@@ -187,6 +188,8 @@ def prepare_packed_tiles(packed, *, has_weights: bool) -> dict:
     }
     if has_weights:
         tiles["weights"] = jnp.asarray(packed.weights)
+    if packed.run_end is not None:
+        tiles["run_end"] = jnp.asarray(packed.run_end)
     return tiles
 
 

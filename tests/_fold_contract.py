@@ -1,11 +1,15 @@
 """The fold contract between execution backends.
 
-The ``packed`` scan scatters each edge's contribution straight into the
+The ``packed`` scan folds a tile in one of two orders. Its run fold
+(packings whose runs are contiguous and few, the DSSS power-law case)
+sums each destination run as a tree (a segmented scan), then adds the
+run partials left to right across runs and tiles in stream order. Its
+per-edge fold adds each edge's contribution straight into the
 accumulator at its destination, left to right in tile order. The
 ``per_block`` executor first reduces a destination's edges into run
-partials, then folds the partials. Min and
-max are order-free, so those programs agree bit for bit. A float32 sum
-is reassociated, so PageRank and PPR agree to float32 rounding over a
+partials, then folds the partials. Min and max are order-free, so those
+programs agree bit for bit under every fold. A float32 sum is
+reassociated, so PageRank and PPR agree to float32 rounding over a
 destination's in-edges: within ``SUM_RTOL`` relative, plus ``SUM_ATOL``
 absolute for ranks near zero, where a relative bound means nothing.
 Comparisons of one backend with itself (residency tiers, chunking,

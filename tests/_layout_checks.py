@@ -19,6 +19,8 @@ def check_layout(g, packed):
     * ``run_dst`` maps every real run to its global destination and every
       padded slot to the ``n_pad`` drop sentinel.
     * Per-tile interval metadata matches the first edge.
+    * Where the run fold applies, ``run_end`` holds each run's last real
+      slot, increasing over the tile's ``u`` runs and 0 past them.
     """
     e_valid = packed.e_valid
     srcs = np.concatenate(
@@ -57,3 +59,24 @@ def check_layout(g, packed):
         assert (packed.run_dst[t, packed.u[t] :] == g.n_pad).all()
         i, j = packed.src_interval[t], packed.dst_interval[t]
         assert i == packed.src[t, 0] // isz and j == packed.dst[t, 0] // isz
+    check_run_end(packed)
+
+
+def check_run_end(packed):
+    """``run_end`` is ``None`` or each run's last real slot (0 past ``u``),
+    ``R`` a multiple of 128 with ``max(u) ≤ R`` and ``2·R ≤ T``."""
+    run_end = packed.run_end
+    if run_end is None:
+        return
+    R = run_end.shape[1]
+    assert run_end.shape == (packed.num_tiles, R) and run_end.dtype == np.int32
+    assert R % 128 == 0 and packed.u.max() <= R < packed.u.max() + 128
+    assert 2 * R <= packed.tile_edges
+    for t, (e, u) in enumerate(zip(packed.e_valid, packed.u)):
+        rl = packed.run_local[t, :e]
+        assert (np.diff(rl) >= 0).all()
+        last = np.full(u, -1)
+        np.maximum.at(last, rl, np.arange(e))
+        np.testing.assert_array_equal(run_end[t, :u], last)
+        assert (np.diff(run_end[t, :u]) > 0).all()
+        assert (run_end[t, u:] == 0).all()

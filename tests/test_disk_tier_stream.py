@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import ExecutionPlan, GraphSession, PageRank, build_dsss
-from repro.core.iomodel import packed_h2d_bytes
+from repro.core.iomodel import packed_disk_bytes, packed_h2d_bytes
 from repro.graph.preprocess import degree_and_densify
 from repro.obs import REGISTRY, TRACER, disable_tracing, enable_tracing
 
@@ -22,6 +22,7 @@ REPO = Path(__file__).resolve().parents[1]
 CHUNKS = "repro_engine_stream_chunks_total"
 TILES = "repro_engine_tiles_swept_total"
 FETCH = "repro_engine_stream_fetch_seconds_total"
+RUN_FOLD = "repro_engine_run_fold_tiles_total"
 CONFIG = {"generator": "graph500", "scale": 11, "edge_factor": 16,
           "initiator": [0.57, 0.19, 0.19], "undirected": True, "P": 4,
           "tier": "disk", "pinned_tile_share": 0.43, "host_memory_budget": 0}
@@ -80,15 +81,23 @@ def test_counters_charge_the_streamed_chunks(graph, tmp_path):
     splan = sess.packed_stream_plan("spu", PageRank().attr_bytes)
     streamed = splan.num_tiles - splan.pin_tiles
     assert streamed > 0 and splan.chunk_tiles > 1  # several chunks, one shorter
+    assert splan.run_slots > 0  # Graph500's runs take the run fold
     before = _counters()
+    run_fold = REGISTRY.value(RUN_FOLD)
     res = sess.run(_plan())
     chunks, tiles, fetch_s = _counters() - before
     assert tiles - ITERS * splan.pin_tiles == ITERS * streamed  # the pinned slab, then the chunks
+    assert REGISTRY.value(RUN_FOLD) - run_fold == tiles
     assert chunks == ITERS * math.ceil(streamed / splan.chunk_tiles)
     assert fetch_s > 0
-    raw = ITERS * packed_h2d_bytes(streamed, splan.tile_edges)
-    assert res.meters.bytes_disk_read == raw  # host_memory_budget=0: every chunk from the file
-    assert res.meters.bytes_h2d == raw  # the pinned prefix is not charged
+    # host_memory_budget=0: every chunk from the file, less the run_end
+    # leaf, which is derived in RAM; the pinned prefix is not charged.
+    assert res.meters.bytes_disk_read == ITERS * packed_disk_bytes(
+        streamed, splan.tile_edges
+    )
+    assert res.meters.bytes_h2d == ITERS * packed_h2d_bytes(
+        streamed, splan.tile_edges, run_slots=splan.run_slots
+    )
 
 
 def test_the_device_tier_charges_no_stream_counter(graph):

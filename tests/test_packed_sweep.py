@@ -25,11 +25,14 @@ execution — also covered here, along with the layout invariants of
 graphs. The per-vertex message form of the tile gather (one gather per
 edge for unweighted programs without destination aux) is held bitwise to
 the per-edge form, tile by tile, and the scan's step is held to one
-scatter.
+scatter. The run fold (one partial per destination run) is held to the
+per-edge fold — bitwise for min and max, within the fold contract for
+sums — and engages only where the packing admits it.
 """
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _fold_contract import assert_attrs_match
@@ -47,9 +50,10 @@ from repro.core import (
 )
 from repro.core import session as session_mod
 from repro.core.vertex_programs import MaxLabelForward
-from repro.graph.generators import erdos_renyi, ring, zipf
+from repro.graph.generators import erdos_renyi, ring, rmat, zipf
 from repro.graph.preprocess import degree_and_densify
-from repro.storage import write_dsss
+from repro.obs import REGISTRY
+from repro.storage import open_dsss, write_dsss
 
 STRATEGIES = ["spu", "dpu", "mpu"]
 RESIDENCIES = ["device", "host"]
@@ -89,6 +93,15 @@ def _graph(n=150, m=900, seed=0, P=5, weighted=False):
         w = rng.uniform(0.1, 2.0, size=len(src)).astype(np.float32)
     el = degree_and_densify(src, dst, weights=w, drop_self_loops=True)
     return build_dsss(el, P)
+
+
+def _run_graph(P=2, src_sorted=False):
+    """An R-MAT graph whose tiles take the run fold: 21 tiles of 1,024
+    slots (most padded), runs of ~21 edges, the longest 275, R = 256."""
+    el = degree_and_densify(
+        *rmat(10, edge_factor=32, seed=0), drop_self_loops=True
+    )
+    return build_dsss(el, P, src_sorted=src_sorted)
 
 
 def _meters_dict(meters, model_only=False):
@@ -332,7 +345,8 @@ def test_host_residency_runs_packed():
     from repro.core import packed_h2d_bytes
 
     assert r_host.meters.bytes_h2d == r_host.iterations * packed_h2d_bytes(
-        splan.num_tiles - splan.pin_tiles, splan.tile_edges
+        splan.num_tiles - splan.pin_tiles, splan.tile_edges,
+        run_slots=splan.run_slots,
     )
 
 
@@ -583,32 +597,248 @@ def _scatters(jaxpr) -> int:
     return n
 
 
+def _scatter_widths(jaxpr) -> list:
+    """Update widths of the scatter equations in ``jaxpr``, nested calls
+    included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            out.append(eqn.invars[2].aval.shape[-1])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _scatter_widths(sub)
+    return out
+
+
 @pytest.mark.parametrize("prog_cls", [PageRank, BFS])
 def test_scan_step_folds_with_one_scatter(prog_cls):
     """Each scan step folds its tile with one scatter into the
-    accumulator, and the lowered sweep holds no other scatter."""
-    import jax.numpy as jnp
-
-    g = _graph(seed=3)
+    accumulator, and the lowered sweep holds no other scatter. The
+    per-edge fold scatters T updates (one per slot); where the run fold
+    engages, it scatters R (one per run slot, 2·R ≤ T)."""
     prog = prog_cls()
+    for g, fold in ((_graph(seed=3), "per-edge"), (_run_graph(), "run")):
+        packed = g.packed_sweep("adaptive")
+        assert (packed.run_end is None) == (fold == "per-edge")
+        tiles = {
+            k: jnp.asarray(v)
+            for k, v in session_mod._packed_host_chunk(
+                packed, 0, packed.num_tiles, False
+            ).items()
+        }
+        aux = {k: jnp.asarray(v) for k, v in prog.make_aux(g).items()}
+        attrs = jnp.zeros((1, g.n_pad), prog.dtype)
+        args = (prog, attrs, attrs, aux, tiles, jnp.ones(g.P, bool), False)
+        closed = jax.make_jaxpr(
+            session_mod._packed_sweep_impl, static_argnums=(0, 6)
+        )(*args)
+        (scan,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
+        assert _scatters(scan.params["jaxpr"].jaxpr) == 1
+        assert _scatters(closed.jaxpr) == 1
+        width = packed.tile_edges if fold == "per-edge" else packed.run_slots
+        assert _scatter_widths(scan.params["jaxpr"].jaxpr) == [width]
+        assert fold == "per-edge" or 2 * width <= packed.tile_edges
+        lowered = jax.jit(
+            session_mod._packed_sweep_impl,
+            static_argnames=("program", "has_weights"),
+        ).lower(*args[:6], has_weights=False)
+        assert lowered.as_text().count('"stablehlo.scatter"(') == 1
+
+
+MESSAGE_CASES = [
+    "pagerank-k1", "pagerank-k3", "ppr-reset", "bfs", "wcc", "max-label"
+]
+
+
+def _run_fold_case(case):
+    """The run graph's packing, device tiles with and without ``run_end``,
+    and one message case's operands."""
+    g = _run_graph()
+    prog, attrs, aux, aux_batched, row_active = _message_case(
+        case, g, np.random.default_rng(1)
+    )
     packed = g.packed_sweep("adaptive")
-    tiles = {
+    runs = {
         k: jnp.asarray(v)
         for k, v in session_mod._packed_host_chunk(
             packed, 0, packed.num_tiles, False
         ).items()
     }
-    aux = {k: jnp.asarray(v) for k, v in prog.make_aux(g).items()}
-    attrs = jnp.zeros((1, g.n_pad), prog.dtype)
-    args = (prog, attrs, attrs, aux, tiles, jnp.ones(g.P, bool), False)
-    closed = jax.make_jaxpr(
-        session_mod._packed_sweep_impl, static_argnums=(0, 6)
-    )(*args)
-    (scan,) = [e for e in closed.jaxpr.eqns if e.primitive.name == "scan"]
-    assert _scatters(scan.params["jaxpr"].jaxpr) == 1
-    assert _scatters(closed.jaxpr) == 1
-    lowered = jax.jit(
-        session_mod._packed_sweep_impl,
-        static_argnames=("program", "has_weights"),
-    ).lower(*args[:6], has_weights=False)
-    assert lowered.as_text().count('"stablehlo.scatter"(') == 1
+    edges = {k: v for k, v in runs.items() if k != "run_end"}
+    ops = dict(
+        prog=prog,
+        attrs=jnp.asarray(attrs, prog.dtype),
+        aux={k: jnp.asarray(v) for k, v in aux.items()},
+        aux_batched=aux_batched,
+        row_active=jnp.asarray(row_active),
+    )
+    return g, packed, runs, edges, ops
+
+
+def _identities(ops):
+    prog = ops["prog"]
+    ident = session_mod.reduce_identity(prog.reduce, prog.dtype)
+    return jnp.full(ops["attrs"].shape, ident, prog.dtype)
+
+
+def _sweep(ops, tiles, acc=None):
+    return session_mod._packed_sweep_impl(
+        ops["prog"], ops["attrs"], _identities(ops) if acc is None else acc,
+        ops["aux"], tiles, ops["row_active"],
+        has_weights=False, aux_batched=ops["aux_batched"],
+    )
+
+
+@pytest.mark.parametrize("case", MESSAGE_CASES)
+def test_run_fold_matches_per_edge_fold(case):
+    """The run fold (segmented scan, run totals at ``run_end``, one scatter
+    per run) folds the same accumulators as one scatter per edge: bitwise
+    for min and max, within the fold contract for sums — on every tile,
+    padded ones and one with a run longer than 128 edges included, and
+    through the whole scan."""
+    g, packed, runs, edges, ops = _run_fold_case(case)
+    prog = ops["prog"]
+    T = packed.tile_edges
+    assert (packed.e_valid < T).any()  # padded tiles
+    assert np.bincount(g.global_hub_slots()).max() > 128  # a long run
+    vert_active = jnp.asarray(
+        np.repeat(np.asarray(ops["row_active"]), g.n_pad // g.P)
+    )
+    msgs = session_mod._vertex_messages(
+        prog, ops["attrs"], ops["aux"], vert_active, ops["aux_batched"]
+    )
+    contributions = jax.jit(session_mod._message_contributions,
+                            static_argnums=0)
+    fold = jax.jit(session_mod._fold_tile, static_argnums=0)
+    acc_run = acc_edge = _identities(ops)
+    for t in range(packed.num_tiles):
+        run_tile = {k: v[t] for k, v in runs.items()}
+        edge_tile = {k: v[t] for k, v in edges.items()}
+        contrib = contributions(prog, msgs, run_tile)
+        acc_run = fold(prog, contrib, run_tile, acc_run)
+        acc_edge = fold(prog, contrib, edge_tile, acc_edge)
+        assert_attrs_match(
+            np.asarray(acc_edge), np.asarray(acc_run), prog.reduce
+        )
+    swept_run, swept_edge = _sweep(ops, runs), _sweep(ops, edges)
+    np.testing.assert_array_equal(np.asarray(swept_run), np.asarray(acc_run))
+    np.testing.assert_array_equal(np.asarray(swept_edge), np.asarray(acc_edge))
+    assert_attrs_match(
+        np.asarray(swept_edge), np.asarray(swept_run), prog.reduce
+    )
+
+
+@pytest.mark.parametrize("case", MESSAGE_CASES)
+def test_run_fold_scans_stay_bitwise(case):
+    """Under the run fold, a selective scan over the active tiles (padded
+    to a larger bucket with emptied tiles) and the scan cut into chunks of
+    tiles equal the full scan bit for bit: a tile's fold depends only on
+    the tile."""
+    from repro.core.dsss import active_tile_mask, next_bucket, tile_source_spans
+
+    g, packed, runs, edges, ops = _run_fold_case(case)
+    full = np.asarray(_sweep(ops, runs))
+    first, last = tile_source_spans(packed, g.interval_size)
+    active = np.flatnonzero(
+        active_tile_mask(np.asarray(ops["row_active"]), first, last)
+    )
+    bucket = next_bucket(active.size + 1)
+    idx = np.zeros(bucket, np.int32)
+    idx[: active.size] = active
+    selected = session_mod._packed_sweep_select_impl(
+        ops["prog"], ops["attrs"], _identities(ops), ops["aux"], runs, jnp.asarray(idx), jnp.asarray(np.int32(active.size)),
+        ops["row_active"], has_weights=False, aux_batched=ops["aux_batched"],
+    )
+    np.testing.assert_array_equal(np.asarray(selected), full)
+    acc = None
+    for lo in range(0, packed.num_tiles, 4):
+        acc = _sweep(ops, {k: v[lo : lo + 4] for k, v in runs.items()}, acc)
+    np.testing.assert_array_equal(np.asarray(acc), full)
+
+
+def _run_fold_tiles(sess, plan):
+    """(result, tiles swept, run-fold tiles) of one run."""
+    tiles = REGISTRY.value("repro_engine_tiles_swept_total")
+    run_fold = REGISTRY.value("repro_engine_run_fold_tiles_total")
+    res = sess.run(plan)
+    return (
+        res,
+        REGISTRY.value("repro_engine_tiles_swept_total") - tiles,
+        REGISTRY.value("repro_engine_run_fold_tiles_total") - run_fold,
+    )
+
+
+@pytest.mark.parametrize("residency", ["device", "host", "disk"])
+def test_run_fold_session_parity(tmp_path, residency):
+    """Through a session, the run fold's PageRank matches the per-block
+    executor within the fold contract, its BFS bit for bit, every tier
+    equals the device tier bitwise, and every swept tile is counted as a
+    run-fold tile."""
+    g = _run_graph()
+    budget = g.total_edge_bytes(8) // 3
+    if residency == "disk":
+        path = str(tmp_path / "runs.dsss")
+        write_dsss(g, path)
+        sess = GraphSession.open(path, memory_budget=budget,
+                                 host_memory_budget=budget)
+    else:
+        sess = GraphSession(
+            g, memory_budget=None if residency == "device" else budget,
+            residency=residency,
+        )
+    assert sess.resolved_residency() == residency
+    device = GraphSession(g)
+    for prog, kwargs in (
+        (PageRank(), dict(max_iters=6, tol=0.0)),
+        (BFS(), dict(max_iters=g.n + 1, program_kwargs={"root": 3})),
+    ):
+        plan = ExecutionPlan(prog, strategy="spu", execution="packed", **kwargs)
+        res, tiles, run_fold = _run_fold_tiles(sess, plan)
+        assert tiles > 0 and run_fold == tiles
+        np.testing.assert_array_equal(res.attrs, device.run(plan).attrs)
+        pb = device.run(dataclasses.replace(plan, execution="per_block"))
+        assert_attrs_match(pb.attrs, res.attrs, prog.reduce)
+    if residency != "device":
+        assert res.meters.bytes_h2d > 0  # streamed, run_end with each chunk
+
+
+@pytest.mark.parametrize("layout", ["one-edge-runs", "src-sorted"])
+def test_per_edge_fold_where_runs_do_not_pay(layout):
+    """The run fold stays off where it would not pay or not hold: a graph
+    whose runs are mostly one edge (2·R > T), and a ``src_sorted`` layout's
+    sub-shard tiles (runs not contiguous). Those sweep with the per-edge
+    fold, count no run-fold tile, and still match the per-block executor."""
+    if layout == "one-edge-runs":
+        g = _graph(n=300, m=2400, seed=4, P=6)
+        packed = g.packed_sweep("adaptive")
+        R = -(-int(packed.u.max()) // 128) * 128
+        assert 2 * R > packed.tile_edges
+    else:
+        g = _run_graph(src_sorted=True)
+        packed = g.packed_sweep("subshard")
+        R = -(-int(packed.u.max()) // 128) * 128
+        assert 2 * R <= packed.tile_edges  # only the order rules it out
+    assert packed.run_end is None and packed.run_slots == 0
+    sess = GraphSession(g)
+    plan = ExecutionPlan(PageRank(), max_iters=4, tol=0.0, execution="packed")
+    res, tiles, run_fold = _run_fold_tiles(sess, plan)
+    assert tiles > 0 and run_fold == 0
+    pb = sess.run(dataclasses.replace(plan, execution="per_block"))
+    assert_attrs_match(pb.attrs, res.attrs, PageRank.reduce)
+
+
+@pytest.mark.parametrize("P", [2, 4])
+def test_run_end_derivation(tmp_path, P):
+    """``run_end`` is (NT, R): each run's last slot, increasing over the
+    tile's runs, 0 past them; a ``.dsss`` store opened from disk derives
+    the same array from its stored ``run_local``."""
+    from _layout_checks import check_run_end
+
+    g = _run_graph(P)
+    packed = g.packed_sweep("adaptive")
+    assert packed.run_end is not None
+    assert packed.run_end.shape == (packed.num_tiles, packed.run_slots)
+    check_run_end(packed)
+    path = str(tmp_path / "g.dsss")
+    write_dsss(g, path)
+    stored = open_dsss(path).packed()
+    np.testing.assert_array_equal(stored.run_end, packed.run_end)

@@ -104,6 +104,7 @@ class TestHostDeviceBitIdentity:
                     splan.chunk_tiles,
                 ),
                 splan.tile_edges,
+                run_slots=splan.run_slots,
             )
             for log_s in host.activity_log
         )

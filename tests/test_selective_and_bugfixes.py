@@ -227,7 +227,8 @@ class TestSelectiveExecution:
         assert on.iterations == off.iterations
         splan = sess.packed_stream_plan("spu", BFS().attr_bytes)
         full_sweep = packed_h2d_bytes(
-            splan.num_tiles - splan.pin_tiles, splan.tile_edges
+            splan.num_tiles - splan.pin_tiles, splan.tile_edges,
+            run_slots=splan.run_slots,
         )
         per_sweep = [
             packed_h2d_bytes(
@@ -237,6 +238,7 @@ class TestSelectiveExecution:
                     splan.chunk_tiles,
                 ),
                 splan.tile_edges,
+                run_slots=splan.run_slots,
             )
             for log in on.activity_log
         ]

@@ -153,6 +153,7 @@ class TestMeterExactness:
                     splan.chunk_tiles,
                 ),
                 splan.tile_edges,
+                run_slots=splan.run_slots,
             )
             for log_s in res.activity_log
         )

@@ -8,7 +8,8 @@ one process may load the TPU library, and under several pytest workers
 only the worker given this file may.
 
 * The XLA scan sweep, its selective variant and the batched apply at the
-  ``chip_smoke.py`` size (n ≈ 4.85 M, ~67 M edge slots): each fits HBM.
+  ``chip_smoke.py`` size (n ≈ 4.85 M, ~67 M edge slots): each fits HBM,
+  under the per-edge fold and under the run fold.
 * ``execution="auto"`` resolves to the scan, and only the CPU backend
   interprets Pallas kernels.
 """
@@ -64,12 +65,14 @@ def _pagerank_aux(n_pad, sharding, K=None):
     }
 
 
-def _tiles(NT, T, sharding):
+def _tiles(NT, T, sharding, run_slots=0):
     tiles = {
         k: _sds((NT, T), jnp.int32, sharding)
         for k in ("src", "dst", "run_local", "run_dst")
     }
     tiles["e_valid"] = _sds((NT,), jnp.int32, sharding)
+    if run_slots:
+        tiles["run_end"] = _sds((NT, run_slots), jnp.int32, sharding)
     return tiles
 
 
@@ -91,15 +94,13 @@ def _fits_hbm(compiled):
     return total
 
 
-@pytest.mark.parametrize(
-    "program,K", [("pagerank", 1), ("bfs", 1), ("bfs", 16)]
-)
-def test_scan_sweep_and_apply_fit_hbm_at_smoke_size(one_chip, program, K):
+def _sweeps_fit_hbm(one_chip, program, K, run_slots=0):
+    """Compile the scan sweep and its selective variant; each fits HBM."""
     prog, dtype, aux = _case(program, one_chip, SMOKE_N_PAD)
-    sweep, apply_all = session_mod._packed_jits(True)
+    sweep, _ = session_mod._packed_jits(True)
     select = session_mod._packed_select_jits(True)
     flat = _sds((K, SMOKE_N_PAD), dtype, one_chip)
-    tiles = _tiles(SMOKE_NT, SMOKE_T, one_chip)
+    tiles = _tiles(SMOKE_NT, SMOKE_T, one_chip, run_slots)
     row_active = _sds((SMOKE_P,), jnp.bool_, one_chip)
     static = dict(has_weights=False, aux_batched=False)
 
@@ -116,6 +117,15 @@ def test_scan_sweep_and_apply_fit_hbm_at_smoke_size(one_chip, program, K):
             row_active, **static,
         ).compile()
     )
+
+
+@pytest.mark.parametrize(
+    "program,K", [("pagerank", 1), ("bfs", 1), ("bfs", 16)]
+)
+def test_scan_sweep_and_apply_fit_hbm_at_smoke_size(one_chip, program, K):
+    _sweeps_fit_hbm(one_chip, program, K)
+    prog, dtype, aux = _case(program, one_chip, SMOKE_N_PAD)
+    _, apply_all = session_mod._packed_jits(True)
     isz = SMOKE_N_PAD // SMOKE_P
     rows = _sds((K, SMOKE_P, isz), dtype, one_chip)
     globals_ = (
@@ -130,6 +140,15 @@ def test_scan_sweep_and_apply_fit_hbm_at_smoke_size(one_chip, program, K):
             aux_batched=False,
         ).compile()
     )
+
+
+@pytest.mark.parametrize(
+    "program,K", [("pagerank", 1), ("bfs", 1), ("bfs", 16)]
+)
+def test_run_fold_sweeps_fit_hbm_at_smoke_size(one_chip, program, K):
+    """The run fold (a ``run_end`` leaf of R = T/4 run slots) compiles for
+    the chip and fits HBM, full and selective."""
+    _sweeps_fit_hbm(one_chip, program, K, run_slots=SMOKE_T // 4)
 
 
 def test_auto_rule_and_interpret_resolution(topo, monkeypatch):
